@@ -65,8 +65,9 @@ def telemetry_block(*, phases=None, model_flops_per_call=None,
                     measured_collective_bytes=None, **extra) -> dict:
     """Assemble the optional ``telemetry`` block a bench attaches to its
     BENCH_*.json payload (docs/observability.md): phase wall breakdown,
-    achieved MFU (``model_flops_per_call / wall_s`` against
-    ``n_devices × PEAK_FLOPS``), and the expected (CommRecord tape) vs
+    achieved MFU (``model_flops_per_call / wall_s`` against ``n_devices``
+    times the local device's peak; ``None`` for a device not in
+    ``DEVICE_PEAKS``), and the expected (CommRecord tape) vs
     measured (compiled HLO) collective bytes.
 
     Informational for now: scripts/bench_gate.py ignores metrics absent
@@ -79,10 +80,11 @@ def telemetry_block(*, phases=None, model_flops_per_call=None,
     if wall_s is not None:
         t["wall_s"] = float(wall_s)
     if model_flops_per_call and wall_s:
-        from repro.launch.hlo_analysis import PEAK_FLOPS
+        from repro.launch.hlo_analysis import device_peak_flops
         achieved = model_flops_per_call / wall_s
+        peak = device_peak_flops()
         t["achieved_flops"] = achieved
-        t["mfu"] = achieved / (PEAK_FLOPS * max(n_devices, 1))
+        t["mfu"] = achieved / (peak * max(n_devices, 1)) if peak else None
     if expected_collective_bytes is not None:
         t["expected_collective_bytes"] = float(expected_collective_bytes)
     if measured_collective_bytes is not None:
@@ -102,11 +104,14 @@ def _block(out):
 def run_subprocess_bench(code: str, *, devices: int = 8,
                          timeout: int = 1200) -> dict:
     """Run `code` (which must print a JSON dict on its last line) in a
-    subprocess with N virtual devices."""
+    subprocess with N virtual host devices. The child is pinned to the
+    CPU platform: the parent may already hold the chip on a TPU host,
+    and one chip belongs to one process."""
     prelude = (
         "import os\n"
         f"os.environ['XLA_FLAGS'] = "
         f"'--xla_force_host_platform_device_count={devices}'\n"
+        "os.environ['JAX_PLATFORMS'] = 'cpu'\n"
         f"import sys; sys.path.insert(0, {SRC!r})\n"
         f"sys.path.insert(0, {ROOT!r})\n")   # benchmarks.common importable
     proc = subprocess.run([sys.executable, "-c", prelude + code],

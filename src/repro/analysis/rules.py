@@ -217,8 +217,7 @@ def check_tracer_isinstance(ctx: FileContext) -> List[Finding]:
             out.append(ctx.finding(
                 "JL103", node,
                 "isinstance(x, ...Tracer) — use repro.core.compat."
-                "is_tracer, which tracks the Tracer class across the "
-                "pinned jax versions"))
+                "is_tracer, the one place that names the Tracer class"))
     return out
 
 
@@ -385,10 +384,10 @@ route timing through repro.obs (scoped_timer / Fence).
     "JL103": """\
 JL103 — isinstance(x, jax.core.Tracer)
 
-jax.core.Tracer moved across the jax versions this repo pins
-(jax.core -> jax._src.core re-exports). repro/core/compat.py owns the
-version dance and exports is_tracer(); direct isinstance checks bypass
-it and break on the next pin bump.
+Where jax exports the Tracer class is a jax decision (jax.core today;
+jax.extend.core has none), so the repo names it in exactly one place:
+repro/core/compat.py exports is_tracer(). Direct isinstance checks
+scatter that decision and each breaks when jax moves the class.
 
 Fix: from repro.core.compat import is_tracer; is_tracer(x).
 """,

@@ -306,7 +306,8 @@ def train_step_axis_budget(mesh, *, n_sp_layers: int,
                            comm_strategy: str = "allgather",
                            microbatches: int = 1,
                            backward: str = "autodiff",
-                           zero1: bool = True) -> AxisBudget:
+                           zero1: bool = True,
+                           remat: str = "none") -> AxisBudget:
     """What one compiled (scan-unrolled) DP×SP(×TP) train step may put
     on the wire — the LASP-2(H) composition claim written down:
 
@@ -328,6 +329,10 @@ def train_step_axis_budget(mesh, *, n_sp_layers: int,
     * ZeRO-1 only: 1 all-gather over the optimizer-shard axes — ``data``
       on 2D, ``(data, model)`` on 3D (the parameter re-assembly after
       the sharded update).
+
+    ``remat="full"`` replays every layer's forward in the backward, so
+    each forward exchange above runs twice per step (still one per layer
+    forward).
     """
     nontrivial = tuple(n for n in mesh.axis_names if mesh.shape[n] > 1)
     dp = mesh.shape.get(DATA_AXIS, 1)
@@ -342,23 +347,24 @@ def train_step_axis_budget(mesh, *, n_sp_layers: int,
         if n and axes:
             counts[(op, axes)] = counts.get((op, axes), 0) + n
 
+    fwd = 2 if remat == "full" else 1     # forward passes per step
     if seq_axes and n_sp_layers:
         per_pass = n_sp_layers * microbatches
+        add("all-gather", seq_axes, fwd * per_pass)
         if backward == "faithful":
-            add("all-gather", seq_axes, 2 * per_pass)
-        else:
             add("all-gather", seq_axes, per_pass)
+        else:
             add("reduce-scatter", seq_axes, per_pass)
     if seq_axes and n_hybrid_layers:
         per_pass = n_hybrid_layers * microbatches
         if comm_strategy == "ulysses":
             a2a_axes = (MODEL_AXIS,) if tp > 1 else (SEQ_AXIS,)
-            add("all-to-all", a2a_axes, 4 * per_pass)  # 2 fwd + 2 bwd
+            add("all-to-all", a2a_axes, (2 * fwd + 2) * per_pass)
             if tp > 1 and sp > 1:
-                add("all-gather", (SEQ_AXIS,), 2 * per_pass)
+                add("all-gather", (SEQ_AXIS,), 2 * fwd * per_pass)
                 add("reduce-scatter", (SEQ_AXIS,), 2 * per_pass)
         else:
-            add("all-gather", seq_axes, 2 * per_pass)
+            add("all-gather", seq_axes, 2 * fwd * per_pass)
             add("reduce-scatter", seq_axes, 2 * per_pass)
     counts[("all-reduce", nontrivial)] = 1
     zero_axes = tuple(a for a in (DATA_AXIS, MODEL_AXIS)
@@ -367,7 +373,8 @@ def train_step_axis_budget(mesh, *, n_sp_layers: int,
         add("all-gather", zero_axes, 1)
     return AxisBudget(counts, note=f"dp={dp} sp={sp} tp={tp} "
                                    f"layers={n_sp_layers}"
-                                   f"+{n_hybrid_layers}h A={microbatches}")
+                                   f"+{n_hybrid_layers}h A={microbatches} "
+                                   f"remat={remat}")
 
 
 def check_axis_budget(hlo_text: str, mesh,

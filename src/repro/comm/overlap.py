@@ -18,10 +18,9 @@ dependency structure, not threads:
   after compute finishes. This is the A/B baseline
   ``benchmarks/comm_strategies.py`` measures overlap against.
 
-``optimization_barrier`` has no differentiation rule on older jax
-(0.4.x), so it is wrapped in a ``custom_vjp`` that passes cotangents
-straight through — the serialization applies to the forward schedule,
-which is what the A/B compares.
+The barrier is wrapped in a ``custom_vjp`` that passes cotangents
+straight through, so the serialization applies to the forward schedule
+only, which is what the A/B compares.
 """
 
 from __future__ import annotations

@@ -6,9 +6,12 @@ compute after the K/V AllGather — paper Alg. 7 line 7) and by prefill.
 Forward grid = ``(B, Hq, nq, kv_band)``; the kv axis is the innermost
 sequential axis; ``(m, l, acc)`` live in VMEM scratch and are reset when
 the band index is 0. The per-row softmax statistics ``lse = m + log l``
-are written out as a second output — the backward residuals of the
-standard flash scheme (Dao 2023; Lightning Attention-2 keeps the same
-tile loop resident on-chip for its backward, the pattern followed here).
+are written out as a second output, a ``(B, Hq, 1, Sq)`` array in
+``(1, block_q)`` rows (``repro.kernels.layout``) — the backward
+residuals of the standard flash scheme (Dao 2023; Lightning Attention-2
+keeps the same tile loop resident on-chip for its backward, the pattern
+followed here). A traced ``q_offset`` reaches the kernels as a scalar
+in SMEM.
 
 Causal grid trimming: the kv grid axis is a *band*, not the full kv
 extent — for each q block the index maps offset by that block's first
@@ -56,7 +59,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import compat as _compat
+from repro.kernels.layout import col_to_row, row_to_col
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
@@ -198,7 +201,7 @@ def _fwd_kernel(qoff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    qoff = q_offset if q_offset is not None else qoff_ref[0, 0]
+    qoff = q_offset if q_offset is not None else qoff_ref[0]
     lo = kv_lo(iq)
     ik = jnp.clip(lo + ikb, 0, jnp.maximum(kv_hi(iq), 0))
     q_start = iq * block_q
@@ -225,22 +228,22 @@ def _fwd_kernel(qoff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                            kv_len=kv_len)
         s = jnp.where(mask, s, neg)
 
-        m_prev = m_scr[:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_scr[...]                            # (bq, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
         p = jnp.where(mask, p, 0.0)
         corr = jnp.exp(m_prev - m_new)
-        l_scr[:, 0] = l_scr[:, 0] * corr + jnp.sum(p, axis=-1)
-        acc_scr[...] = acc_scr[...] * corr[:, None] + jax.lax.dot_general(
+        l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        m_scr[:, 0] = m_new
+        m_scr[...] = m_new
 
     @pl.when(ikb == kv_band - 1)
     def _finalize():
-        l = jnp.maximum(l_scr[:, 0], 1e-30)
-        o_ref[0, 0] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
-        lse_ref[0, 0] = m_scr[:, 0] + jnp.log(l)
+        l = jnp.maximum(l_scr[...], 1e-30)             # (bq, 1)
+        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
+        lse_ref[0, 0] = col_to_row(m_scr[...] + jnp.log(l))
 
 
 def _fwd_call(q, k, v, qoff_arr, *, causal, sliding_window, scale,
@@ -268,7 +271,7 @@ def _fwd_call(q, k, v, qoff_arr, *, causal, sliding_window, scale,
         kernel,
         grid=(b, hq, nq, kv_band),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda b_, h, iq, ikb: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1, block_q, dh),
                          lambda b_, h, iq, ikb: (b_, h, iq, 0)),
             pl.BlockSpec((1, 1, block_k, dh), kv_im),
@@ -277,19 +280,19 @@ def _fwd_call(q, k, v, qoff_arr, *, causal, sliding_window, scale,
         out_specs=[
             pl.BlockSpec((1, 1, block_q, dh),
                          lambda b_, h, iq, ikb: (b_, h, iq, 0)),
-            pl.BlockSpec((1, 1, block_q),
-                         lambda b_, h, iq, ikb: (b_, h, iq)),
+            pl.BlockSpec((1, 1, 1, block_q),
+                         lambda b_, h, iq, ikb: (b_, h, 0, iq)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, hq, sq, dh), q.dtype),
-            jax.ShapeDtypeStruct((b, hq, sq), jnp.float32),
+            jax.ShapeDtypeStruct((b, hq, 1, sq), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, dh), jnp.float32),
         ],
-        compiler_params=_compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -313,7 +316,7 @@ def _bwd_dq_kernel(qoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    qoff = q_offset if q_offset is not None else qoff_ref[0, 0]
+    qoff = q_offset if q_offset is not None else qoff_ref[0]
     lo = kv_lo(iq)
     ik = jnp.clip(lo + ikb, 0, jnp.maximum(kv_hi(iq), 0))
     q_start = iq * block_q
@@ -330,19 +333,19 @@ def _bwd_dq_kernel(qoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         k = k_ref[0, 0].astype(jnp.float32)        # (bk, dh)
         v = v_ref[0, 0].astype(jnp.float32)        # (bk, dh)
         do = do_ref[0, 0].astype(jnp.float32)      # (bq, dh)
-        lse = lse_ref[0, 0]                        # (bq,)
-        delta = delta_ref[0, 0]                    # (bq,)
+        lse = row_to_col(lse_ref[0, 0])            # (bq, 1)
+        delta = row_to_col(delta_ref[0, 0])        # (bq, 1)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
         mask = _block_mask(qoff, q_start, k_start, block_q, block_k,
                            causal=causal, sliding_window=sliding_window,
                            kv_len=kv_len)
-        p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)   # (bq, bk)
+        p = jnp.where(mask, jnp.exp(s - lse), 0.0)            # (bq, bk)
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)                # (bq, bk)
-        ds = p * (dp - delta[:, None])
+        ds = p * (dp - delta)
         dq_scr[...] = dq_scr[...] + jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -365,7 +368,7 @@ def _bwd_dkv_kernel(qoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    qoff = q_offset if q_offset is not None else qoff_ref[0, 0]
+    qoff = q_offset if q_offset is not None else qoff_ref[0]
     lo = q_lo(ik)
     iq = jnp.clip(lo + iqb, 0, jnp.maximum(q_hi(ik), 0))
     q_start = iq * block_q
@@ -382,22 +385,22 @@ def _bwd_dkv_kernel(qoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         k = k_ref[0, 0].astype(jnp.float32)        # (bk, dh)
         v = v_ref[0, 0].astype(jnp.float32)        # (bk, dh)
         do = do_ref[0, 0].astype(jnp.float32)      # (bq, dh)
-        lse = lse_ref[0, 0]                        # (bq,)
-        delta = delta_ref[0, 0]                    # (bq,)
+        lse = row_to_col(lse_ref[0, 0])            # (bq, 1)
+        delta = row_to_col(delta_ref[0, 0])        # (bq, 1)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
         mask = _block_mask(qoff, q_start, k_start, block_q, block_k,
                            causal=causal, sliding_window=sliding_window,
                            kv_len=kv_len)
-        p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)   # (bq, bk)
+        p = jnp.where(mask, jnp.exp(s - lse), 0.0)            # (bq, bk)
         dv_scr[...] = dv_scr[...] + jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)                # (bk, dh)
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)                # (bq, bk)
-        ds = p * (dp - delta[:, None])
+        ds = p * (dp - delta)
         dk_scr[...] = dk_scr[...] + jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)                # (bk, dh)
@@ -415,7 +418,8 @@ def _bwd_call(q, k, v, qoff_arr, o, lse, do, *, causal, sliding_window,
     rep = hq // hkv
     nq, nkv = sq // block_q, sk // block_k
     nkv_real = -(-kv_len // block_k)
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                    axis=-1)[:, :, None, :]            # (b, hq, 1, sq) rows
 
     kv_lo, kv_hi, kv_band = _kv_band(
         nq=nq, nkv_real=nkv_real, block_q=block_q, block_k=block_k,
@@ -426,7 +430,7 @@ def _bwd_call(q, k, v, qoff_arr, o, lse, do, *, causal, sliding_window,
         return (b_, h // rep_, ik, 0)
 
     q_im = lambda b_, h, iq, ikb: (b_, h, iq, 0)
-    stat_im = lambda b_, h, iq, ikb: (b_, h, iq)
+    stat_im = lambda b_, h, iq, ikb: (b_, h, 0, iq)
 
     dq = pl.pallas_call(
         functools.partial(
@@ -436,18 +440,18 @@ def _bwd_call(q, k, v, qoff_arr, o, lse, do, *, causal, sliding_window,
             block_q=block_q, block_k=block_k),
         grid=(b, hq, nq, kv_band),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda b_, h, iq, ikb: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1, block_q, dh), q_im),
             pl.BlockSpec((1, 1, block_k, dh), kv_im),
             pl.BlockSpec((1, 1, block_k, dh), kv_im),
             pl.BlockSpec((1, 1, block_q, dh), q_im),
-            pl.BlockSpec((1, 1, block_q), stat_im),
-            pl.BlockSpec((1, 1, block_q), stat_im),
+            pl.BlockSpec((1, 1, 1, block_q), stat_im),
+            pl.BlockSpec((1, 1, 1, block_q), stat_im),
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, dh), q_im),
         out_shape=jax.ShapeDtypeStruct((b, hq, sq, dh), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, dh), jnp.float32)],
-        compiler_params=_compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -464,7 +468,7 @@ def _bwd_call(q, k, v, qoff_arr, o, lse, do, *, causal, sliding_window,
 
     def statg_im(b_, g, ik, ig, iqb, rep_=rep):
         iq = jnp.clip(q_lo(ik) + iqb, 0, jnp.maximum(q_hi(ik), 0))
-        return (b_, g * rep_ + ig, iq)
+        return (b_, g * rep_ + ig, 0, iq)
 
     kvg_im = lambda b_, g, ik, ig, iqb: (b_, g, ik, 0)
 
@@ -476,13 +480,13 @@ def _bwd_call(q, k, v, qoff_arr, o, lse, do, *, causal, sliding_window,
             block_q=block_q, block_k=block_k),
         grid=(b, hkv, nkv, rep, q_band),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda b_, g, ik, ig, iqb: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1, block_q, dh), qg_im),
             pl.BlockSpec((1, 1, block_k, dh), kvg_im),
             pl.BlockSpec((1, 1, block_k, dh), kvg_im),
             pl.BlockSpec((1, 1, block_q, dh), qg_im),
-            pl.BlockSpec((1, 1, block_q), statg_im),
-            pl.BlockSpec((1, 1, block_q), statg_im),
+            pl.BlockSpec((1, 1, 1, block_q), statg_im),
+            pl.BlockSpec((1, 1, 1, block_q), statg_im),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_k, dh), kvg_im),
@@ -496,7 +500,7 @@ def _bwd_call(q, k, v, qoff_arr, o, lse, do, *, causal, sliding_window,
             pltpu.VMEM((block_k, dh), jnp.float32),
             pltpu.VMEM((block_k, dh), jnp.float32),
         ],
-        compiler_params=_compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary", "arbitrary")),
         interpret=interpret,
@@ -576,9 +580,9 @@ def flash_attention(q, k, v, *, causal: bool = True, sliding_window=None,
                          f"({block_q}, {block_k})")
     if isinstance(q_offset, (int, np.integer)):
         q_off_static, qoff_arr = int(q_offset), \
-            jnp.full((1, 1), int(q_offset), jnp.int32)
+            jnp.full((1,), int(q_offset), jnp.int32)
     else:   # traced (SP rank offset): band untrimmed, masked at runtime
         q_off_static = None
-        qoff_arr = jnp.asarray(q_offset, jnp.int32).reshape(1, 1)
+        qoff_arr = jnp.asarray(q_offset, jnp.int32).reshape(1)
     return _flash(q, k, v, qoff_arr, causal, sliding_window, float(scale),
                   q_off_static, int(kv_len), block_q, block_k, interpret)

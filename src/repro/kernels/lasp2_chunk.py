@@ -41,6 +41,9 @@ the Pallas path trainable end-to-end.
 Layout: inputs are flattened to ``(BH, S, d)``; grid = ``(BH, S//BLOCK)``
 with ``dimension_semantics=("parallel", "arbitrary")`` so distinct
 batch·head programs parallelize across cores while blocks run in order.
+``log_a`` and its gradient move as ``(BH, 1, S)`` arrays in ``(1, BLOCK)``
+rows (``repro.kernels.layout``); the total log decay is a plain sum
+outside the kernel.
 """
 
 from __future__ import annotations
@@ -52,33 +55,35 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import compat as _compat
+from repro.kernels.layout import col_to_row, cumsum_col, suffix_sum_row, tri
 
 DEFAULT_BLOCK = 128
 
 
-def _kernel(q_ref, k_ref, v_ref, la_ref, o_ref, state_ref, ld_ref,
-            state_scratch, ld_scratch, *, nblocks: int):
+def _decay_mat(cb):
+    """D_ij = exp(cb_i - cb_j) for i >= j else 0 (all factors <= 1);
+    ``cb`` is the ``(c, 1)`` cumulative log decay column."""
+    row, col = tri(cb.shape[0])
+    diff = cb - col_to_row(cb)
+    return jnp.where(row >= col, jnp.exp(jnp.minimum(diff, 0.0)), 0.0)
+
+
+def _kernel(q_ref, k_ref, v_ref, la_ref, o_ref, state_ref, state_scratch,
+            *, nblocks: int):
     blk = pl.program_id(1)
 
     @pl.when(blk == 0)
     def _init():
         state_scratch[...] = jnp.zeros_like(state_scratch)
-        ld_scratch[...] = jnp.zeros_like(ld_scratch)
 
     q = q_ref[0].astype(jnp.float32)          # (C, dk)
     k = k_ref[0].astype(jnp.float32)          # (C, dk)
     v = v_ref[0].astype(jnp.float32)          # (C, dv)
-    la = la_ref[0].astype(jnp.float32)        # (C,)
+    la = la_ref[0].astype(jnp.float32)        # (1, C)
 
-    cb = jnp.cumsum(la)                       # inclusive cumulative log decay
-    a_blk = cb[-1]
-    c = q.shape[0]
-    # D_ij = exp(cb_i - cb_j) for i >= j else 0 — all factors <= 1.
-    diff = cb[:, None] - cb[None, :]
-    row = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
-    dmat = jnp.where(row >= col, jnp.exp(jnp.minimum(diff, 0.0)), 0.0)
+    cb = cumsum_col(la)                       # (C, 1) inclusive log decay
+    a_blk = jnp.sum(la, axis=1, keepdims=True)  # (1, 1)
+    dmat = _decay_mat(cb)
 
     scores = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
@@ -89,22 +94,20 @@ def _kernel(q_ref, k_ref, v_ref, la_ref, o_ref, state_ref, ld_ref,
     # inter (within-device, previous blocks): (q ⊙ b) @ S_carry
     state = state_scratch[...]
     o_inter = jax.lax.dot_general(
-        q * jnp.exp(cb)[:, None], state, (((1,), (0,)), ((), ())),
+        q * jnp.exp(cb), state, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
     o_ref[0] = (o_intra + o_inter).astype(o_ref.dtype)
 
     # state update: S <- exp(A) S + (k ⊙ exp(A - cb))^T v
-    kw = k * jnp.exp(a_blk - cb)[:, None]
+    kw = k * jnp.exp(a_blk - cb)
     s_new = jnp.exp(a_blk) * state + jax.lax.dot_general(
         kw, v, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
     state_scratch[...] = s_new
-    ld_scratch[0, 0] = ld_scratch[0, 0] + a_blk
 
     @pl.when(blk == nblocks - 1)
     def _finalize():
         state_ref[0] = s_new
-        ld_ref[0, 0] = ld_scratch[0, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("block_size", "interpret"))
@@ -123,49 +126,35 @@ def lasp2_chunk_fwd(q, k, v, log_a, *, block_size: int = DEFAULT_BLOCK,
 
     grid = (bh, nb)
     kernel = functools.partial(_kernel, nblocks=nb)
-    o, state, ld = pl.pallas_call(
+    o, state = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_size, dk), lambda b, t: (b, t, 0)),
             pl.BlockSpec((1, block_size, dk), lambda b, t: (b, t, 0)),
             pl.BlockSpec((1, block_size, dv), lambda b, t: (b, t, 0)),
-            pl.BlockSpec((1, block_size), lambda b, t: (b, t)),
+            pl.BlockSpec((1, 1, block_size), lambda b, t: (b, 0, t)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_size, dv), lambda b, t: (b, t, 0)),
             pl.BlockSpec((1, dk, dv), lambda b, t: (b, 0, 0)),
-            pl.BlockSpec((1, 1), lambda b, t: (b, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, s, dv), q.dtype),
             jax.ShapeDtypeStruct((bh, dk, dv), jnp.float32),
-            jax.ShapeDtypeStruct((bh, 1), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((dk, dv), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-        ],
-        compiler_params=_compat.tpu_compiler_params(
+        scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="lasp2_chunk_fwd",
-    )(q, k, v, log_a)
-    return o, state, ld[:, 0]
+    )(q, k, v, log_a.reshape(bh, 1, s))
+    return o, state, jnp.sum(log_a.astype(jnp.float32), axis=-1)
 
 
 # ---------------------------------------------------------------------------
 # Backward kernels.
 # ---------------------------------------------------------------------------
-
-def _decay_mat(cb):
-    """D_ij = exp(cb_i - cb_j) for i >= j else 0 (all factors <= 1)."""
-    c = cb.shape[0]
-    diff = cb[:, None] - cb[None, :]
-    row = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
-    return jnp.where(row >= col, jnp.exp(jnp.minimum(diff, 0.0)), 0.0)
-
 
 def _bwd_dq_kernel(k_ref, v_ref, la_ref, do_ref, dq_ref, state_scratch):
     """Forward-order pass: dq_i = dO_i M_i^T, re-carrying the prefix state."""
@@ -177,11 +166,11 @@ def _bwd_dq_kernel(k_ref, v_ref, la_ref, do_ref, dq_ref, state_scratch):
 
     k = k_ref[0].astype(jnp.float32)          # (C, dk)
     v = v_ref[0].astype(jnp.float32)          # (C, dv)
-    la = la_ref[0].astype(jnp.float32)        # (C,)
+    la = la_ref[0].astype(jnp.float32)        # (1, C)
     do = do_ref[0].astype(jnp.float32)        # (C, dv)
 
-    cb = jnp.cumsum(la)
-    a_blk = cb[-1]
+    cb = cumsum_col(la)                       # (C, 1)
+    a_blk = jnp.sum(la, axis=1, keepdims=True)  # (1, 1)
     dmat = _decay_mat(cb)
     # intra: dq_i += sum_{j<=i} e^{cb_i-cb_j} (dO_i·v_j) k_j
     dsc = jax.lax.dot_general(
@@ -194,11 +183,11 @@ def _bwd_dq_kernel(k_ref, v_ref, la_ref, do_ref, dq_ref, state_scratch):
     state = state_scratch[...]
     dq_inter = jax.lax.dot_general(
         do, state, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * jnp.exp(cb)[:, None]
+        preferred_element_type=jnp.float32) * jnp.exp(cb)
     dq_ref[0] = (dq_intra + dq_inter).astype(dq_ref.dtype)
 
     # same carry update as the forward: M <- e^A M + (k ⊙ e^{A-cb})^T v
-    kw = k * jnp.exp(a_blk - cb)[:, None]
+    kw = k * jnp.exp(a_blk - cb)
     state_scratch[...] = jnp.exp(a_blk) * state + jax.lax.dot_general(
         kw, v, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
@@ -214,19 +203,19 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, la_ref, do_ref, o_ref, dstate_ref,
     @pl.when(blk == 0)
     def _init():
         dstate_scratch[...] = dstate_ref[0].astype(jnp.float32)
-        r_scratch[0, 0] = jnp.float32(0.0)
+        r_scratch[...] = jnp.zeros_like(r_scratch)
 
     q = q_ref[0].astype(jnp.float32)          # (C, dk)
     k = k_ref[0].astype(jnp.float32)          # (C, dk)
     v = v_ref[0].astype(jnp.float32)          # (C, dv)
-    la = la_ref[0].astype(jnp.float32)        # (C,)
+    la = la_ref[0].astype(jnp.float32)        # (1, C)
     do = do_ref[0].astype(jnp.float32)        # (C, dv)
     o = o_ref[0].astype(jnp.float32)          # (C, dv)
 
-    cb = jnp.cumsum(la)
-    a_blk = cb[-1]
+    cb = cumsum_col(la)                       # (C, 1)
+    a_blk = jnp.sum(la, axis=1, keepdims=True)  # (1, 1)
     dmat = _decay_mat(cb)
-    w = jnp.exp(a_blk - cb)                    # e^{A - cb_j} <= 1
+    w = jnp.exp(a_blk - cb)                    # (C, 1) e^{A - cb_j} <= 1
     n = dstate_scratch[...]                    # (dk, dv) suffix dstate
 
     # dk_j = sum_{i>=j} e^{cb_i-cb_j}(dO_i·v_j) q_i + w_j (N v_j)
@@ -236,7 +225,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, la_ref, do_ref, o_ref, dstate_ref,
     dk = jax.lax.dot_general(
         dsc, q, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)                    # (C, dk)
-    dk = dk + w[:, None] * jax.lax.dot_general(
+    dk = dk + w * jax.lax.dot_general(
         v, n, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
     # dv_j = sum_{i>=j} e^{cb_i-cb_j}(q_i·k_j) dO_i + w_j (N^T k_j)
@@ -246,22 +235,22 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, la_ref, do_ref, o_ref, dstate_ref,
     dv = jax.lax.dot_general(
         sc, do, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)                    # (C, dv)
-    dv = dv + w[:, None] * jax.lax.dot_general(
+    dv = dv + w * jax.lax.dot_general(
         k, n, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
     # decay grad: dlog_a_m = Σ_{i>=m} r_i (suffix over the whole sequence),
-    # r_i = dO_i·o_i − k_i·dk_i; in-block inclusive suffix cumsum + the
-    # carried sum over later blocks.
-    r = jnp.sum(do * o, axis=-1) - jnp.sum(k * dk, axis=-1)   # (C,)
-    suffix = jnp.sum(r) - jnp.cumsum(r) + r
-    dla_ref[0] = suffix + r_scratch[0, 0]
-    r_scratch[0, 0] = r_scratch[0, 0] + jnp.sum(r)
+    # r_i = dO_i·o_i − k_i·dk_i; in-block inclusive suffix sum (in the
+    # (1, C) row layout of dla) + the carried sum over later blocks.
+    r = (jnp.sum(do * o, axis=1, keepdims=True)
+         - jnp.sum(k * dk, axis=1, keepdims=True))            # (C, 1)
+    dla_ref[0] = suffix_sum_row(r) + r_scratch[...]
+    r_scratch[...] = r_scratch[...] + jnp.sum(r, axis=0, keepdims=True)
 
     # carry to the previous block: N' = e^A N + sum_i e^{cb_i} q_i^T dO_i
-    qw = q * jnp.exp(cb)[:, None]
+    qw = q * jnp.exp(cb)
     dstate_scratch[...] = jnp.exp(a_blk) * n + jax.lax.dot_general(
         qw, do, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
@@ -286,6 +275,8 @@ def lasp2_chunk_bwd(q, k, v, log_a, o, do, dstate, *,
 
     fwd_order = lambda b, t: (b, t, 0)
     rev_order = lambda b, t: (b, nb - 1 - t, 0)
+    rev_row = lambda b, t: (b, 0, nb - 1 - t)
+    la_rows = log_a.reshape(bh, 1, s)
 
     dq = pl.pallas_call(
         _bwd_dq_kernel,
@@ -293,17 +284,17 @@ def lasp2_chunk_bwd(q, k, v, log_a, o, do, dstate, *,
         in_specs=[
             pl.BlockSpec((1, block_size, dk), fwd_order),
             pl.BlockSpec((1, block_size, dv), fwd_order),
-            pl.BlockSpec((1, block_size), lambda b, t: (b, t)),
+            pl.BlockSpec((1, 1, block_size), lambda b, t: (b, 0, t)),
             pl.BlockSpec((1, block_size, dv), fwd_order),
         ],
         out_specs=pl.BlockSpec((1, block_size, dk), fwd_order),
         out_shape=jax.ShapeDtypeStruct((bh, s, dk), q.dtype),
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
-        compiler_params=_compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="lasp2_chunk_bwd_dq",
-    )(k, v, log_a, do)
+    )(k, v, la_rows, do)
 
     dk_out, dv_out, dla = pl.pallas_call(
         _bwd_dkv_kernel,
@@ -312,7 +303,7 @@ def lasp2_chunk_bwd(q, k, v, log_a, o, do, dstate, *,
             pl.BlockSpec((1, block_size, dk), rev_order),
             pl.BlockSpec((1, block_size, dk), rev_order),
             pl.BlockSpec((1, block_size, dv), rev_order),
-            pl.BlockSpec((1, block_size), lambda b, t: (b, nb - 1 - t)),
+            pl.BlockSpec((1, 1, block_size), rev_row),
             pl.BlockSpec((1, block_size, dv), rev_order),
             pl.BlockSpec((1, block_size, dv), rev_order),
             pl.BlockSpec((1, dk, dv), lambda b, t: (b, 0, 0)),
@@ -320,23 +311,23 @@ def lasp2_chunk_bwd(q, k, v, log_a, o, do, dstate, *,
         out_specs=[
             pl.BlockSpec((1, block_size, dk), rev_order),
             pl.BlockSpec((1, block_size, dv), rev_order),
-            pl.BlockSpec((1, block_size), lambda b, t: (b, nb - 1 - t)),
+            pl.BlockSpec((1, 1, block_size), rev_row),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, s, dk), k.dtype),
             jax.ShapeDtypeStruct((bh, s, dv), v.dtype),
-            jax.ShapeDtypeStruct((bh, s), jnp.float32),
+            jax.ShapeDtypeStruct((bh, 1, s), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((dk, dv), jnp.float32),
             pltpu.VMEM((1, 1), jnp.float32),
         ],
-        compiler_params=_compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="lasp2_chunk_bwd_dkv",
-    )(q, k, v, log_a, do, o, dstate)
-    return dq, dk_out, dv_out, dla
+    )(q, k, v, la_rows, do, o, dstate)
+    return dq, dk_out, dv_out, dla.reshape(bh, s)
 
 
 # ---------------------------------------------------------------------------

@@ -20,16 +20,14 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import compat as _compat
 
-
-def _kernel(q_ref, k_ref, v_ref, la_ref, m_ref, ld_ref,
-            o_ref, m_out_ref, ld_out_ref):
+def _kernel(q_ref, k_ref, v_ref, la_ref, m_ref, o_ref, m_out_ref):
     q = q_ref[0].astype(jnp.float32)          # (1, dk)
     k = k_ref[0].astype(jnp.float32)          # (1, dk)
     v = v_ref[0].astype(jnp.float32)          # (1, dv)
-    la = la_ref[0, 0]                         # scalar log decay
+    la = la_ref[0]                            # (1, 1) log decay
     m = m_ref[0]                              # (dk, dv) fp32
 
     a = jnp.exp(la)
@@ -42,7 +40,6 @@ def _kernel(q_ref, k_ref, v_ref, la_ref, m_ref, ld_ref,
                             preferred_element_type=jnp.float32)
     o_ref[0] = o.astype(o_ref.dtype)
     m_out_ref[0] = m_new
-    ld_out_ref[0, 0] = ld_ref[0, 0] + la
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -56,33 +53,29 @@ def lasp2_decode_step(q, k, v, log_a, state, log_decay, *,
     """
     bh, dk = q.shape
     dv = v.shape[-1]
-    la2 = log_a.astype(jnp.float32).reshape(bh, 1)
-    ld2 = log_decay.astype(jnp.float32).reshape(bh, 1)
-    o, m_new, ld_new = pl.pallas_call(
+    la = log_a.astype(jnp.float32)
+    o, m_new = pl.pallas_call(
         _kernel,
         grid=(bh,),
         in_specs=[
             pl.BlockSpec((1, 1, dk), lambda b: (b, 0, 0)),
             pl.BlockSpec((1, 1, dk), lambda b: (b, 0, 0)),
             pl.BlockSpec((1, 1, dv), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, 1), lambda b: (b, 0)),
+            pl.BlockSpec((1, 1, 1), lambda b: (b, 0, 0)),
             pl.BlockSpec((1, dk, dv), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, 1), lambda b: (b, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, dv), lambda b: (b, 0, 0)),
             pl.BlockSpec((1, dk, dv), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, 1), lambda b: (b, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, 1, dv), jnp.float32),
             jax.ShapeDtypeStruct((bh, dk, dv), jnp.float32),
-            jax.ShapeDtypeStruct((bh, 1), jnp.float32),
         ],
-        compiler_params=_compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
         name="lasp2_decode_step",
-    )(q[:, None, :], k[:, None, :], v[:, None, :], la2,
-      state.astype(jnp.float32), ld2)
-    return o[:, 0, :], m_new, ld_new[:, 0]
+    )(q[:, None, :], k[:, None, :], v[:, None, :], la.reshape(bh, 1, 1),
+      state.astype(jnp.float32))
+    return o[:, 0, :], m_new, log_decay.astype(jnp.float32) + la

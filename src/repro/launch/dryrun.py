@@ -1,7 +1,11 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+# A CPU tool that counts: it needs the virtual host devices, never the
+# chip. Its per-cell child processes inherit both settings, so on a TPU
+# host none of them takes the accelerator from a process that holds it.
+os.environ["JAX_PLATFORMS"] = "cpu"
 
-# The two lines above MUST run before any other import (jax locks the
+# The lines above MUST run before any other import (jax locks the
 # device count at first initialization). Everything else follows.
 
 import argparse          # noqa: E402
@@ -50,8 +54,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool, out_dir: str):
         rec["compile_s"] = round(time.time() - t1, 1)
         rec["status"] = "ok"
         rec["memory"] = H.memory_report(compiled)
-        from repro.core.compat import cost_analysis
-        ca = cost_analysis(compiled)
+        ca = compiled.cost_analysis() or {}
         rec["cost"] = {"flops": float(ca.get("flops", 0.0)),
                        "bytes_accessed": float(ca.get("bytes accessed",
                                                       0.0))}

@@ -15,8 +15,10 @@ op (paper §3.4's communication model, generalized):
   all-to-all        (g-1)/g × bytes
   collective-permute  result_bytes
 
-Hardware constants (TPU v5e, per chip): 197 TFLOP/s bf16, 819 GB/s HBM,
-~50 GB/s/link ICI.
+Hardware peaks live in one table keyed by ``jax.Device.device_kind``
+(:data:`DEVICE_PEAKS`). The dry-run roofline projects onto TPU v5e, the
+chip the repo is built for; MFU is reported only for a device in the
+table (``None`` elsewhere, the CPU included).
 """
 
 from __future__ import annotations
@@ -25,9 +27,28 @@ import re
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
-PEAK_FLOPS = 197e12        # bf16 per chip
-HBM_BW = 819e9             # bytes/s per chip
-ICI_BW = 50e9              # bytes/s per link
+# Published per-chip peaks, keyed by the ``device_kind`` JAX reports.
+# Source: Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16,
+# 819 GB/s HBM, 1,600 Gbit/s ICI per chip (4 links -> 50 GB/s per link).
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+ROOFLINE_KIND = "TPU v5 lite"   # the chip the dry-run roofline projects onto
+PEAK_FLOPS = DEVICE_PEAKS[ROOFLINE_KIND]["flops"]      # bf16 per chip
+HBM_BW = DEVICE_PEAKS[ROOFLINE_KIND]["hbm_bw"]         # bytes/s per chip
+ICI_BW = DEVICE_PEAKS[ROOFLINE_KIND]["ici_bw"]         # bytes/s per link
+
+
+def device_peak_flops(device=None) -> Optional[float]:
+    """bf16 peak FLOP/s of ``device`` (default: the first local device),
+    or ``None`` when its ``device_kind`` is not in :data:`DEVICE_PEAKS` —
+    MFU is then not defined, never measured against another chip's peak."""
+    if device is None:
+        import jax
+        device = jax.devices()[0]
+    peaks = DEVICE_PEAKS.get(device.device_kind)
+    return peaks["flops"] if peaks else None
+
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -181,12 +202,28 @@ class Collective:
         return t * self.count
 
 
+_ASYNC_PHASES = ("fused_computation", "async_collective_fusion")
+
+
 def parse_collectives(hlo_text: str, total_devices: int) -> List[Collective]:
     """All collective ops in the compiled module ('-start' variants counted,
     '-done' skipped). NOTE: ops inside while bodies appear once — callers
-    using scans must extrapolate (repro.launch.roofline)."""
+    using scans must extrapolate (repro.launch.roofline).
+
+    TPU spellings: an asynchronous collective fusion is split into start,
+    update and done fusion computations that each hold a copy of the
+    instruction under one ``channel_id`` (counted once), and a
+    reduce-scatter is an all-reduce inside an ``all-reduce-scatter``
+    fusion computation (counted as the reduce-scatter, with its scattered
+    result size)."""
     out = []
+    seen_channels = set()
+    computation = ""
     for line in hlo_text.splitlines():
+        if line and not line[0].isspace() and line.rstrip().endswith("{"):
+            m = re.match(r"(?:ENTRY\s+)?%?([\w.\-]+)", line)
+            computation = m.group(1) if m else ""
+            continue
         stripped = line.strip()
         m = re.match(r"(?:ROOT\s+)?%?[\w.\-]+\s*=\s*(.+?)\s+([\w\-]+)\(",
                      stripped)
@@ -196,11 +233,19 @@ def parse_collectives(hlo_text: str, total_devices: int) -> List[Collective]:
         base = op.replace("-start", "")
         if base not in _COLL_OPS or op.endswith("-done"):
             continue
+        ch = re.search(r"channel_id=(\d+)", stripped)
+        if ch and computation.startswith(_ASYNC_PHASES):
+            if ch.group(1) in seen_channels:
+                continue
+            seen_channels.add(ch.group(1))
         rb = _type_bytes(type_str)
         if base == "all-gather" and op.endswith("-start"):
             rb //= 2   # start ops carry (operand, result) tuple types
-        out.append(Collective(base, rb,
-                              _group_size(stripped, total_devices),
+        group_size = _group_size(stripped, total_devices)
+        if base == "all-reduce" and computation.startswith(
+                "all-reduce-scatter"):
+            base, rb = "reduce-scatter", rb // max(group_size, 1)
+        out.append(Collective(base, rb, group_size,
                               groups=parse_replica_groups(stripped)))
     return out
 
@@ -212,6 +257,26 @@ def collective_counts(hlo_text: str, total_devices: int) -> Dict[str, int]:
     counts: Dict[str, int] = {}
     for c in parse_collectives(hlo_text, total_devices):
         counts[c.op] = counts.get(c.op, 0) + c.count
+    return counts
+
+
+# op_name ends ".../<name>/pallas_call", the name possibly wrapped in
+# transforms: "transpose(jvp(flash_attention_bwd_dq))".
+_KERNEL_RE = re.compile(r'op_name="[^"]*?([\w-]+)\)*/pallas_call"')
+
+
+def tpu_kernels(hlo_text: str) -> Dict[str, int]:
+    """``{kernel name: instruction count}`` of the Pallas kernels in a
+    compiled TPU module: each is a ``tpu_custom_call`` instruction whose
+    metadata names its ``pallas_call(name=...)``. A program that fell back
+    to XLA or to interpret mode has none."""
+    counts: Dict[str, int] = {}
+    for line in hlo_text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        m = _KERNEL_RE.search(line)
+        name = m.group(1) if m else "<unnamed>"
+        counts[name] = counts.get(name, 0) + 1
     return counts
 
 
@@ -255,8 +320,7 @@ class CostVector:
 
 
 def measure(compiled, total_devices: int) -> CostVector:
-    from repro.core.compat import cost_analysis
-    ca = cost_analysis(compiled)
+    ca = compiled.cost_analysis() or {}
     colls = parse_collectives(compiled.as_text(), total_devices)
     summ = collective_summary(colls)
     by_op = {c: summ.get(c, 0.0) for c in _COLL_OPS if c in summ}

@@ -31,24 +31,14 @@ MODEL_AXIS = "model"
 POD_AXIS = "pod"
 
 
-def auto_axis_types(n: int):
-    """``axis_types`` kwargs compatible with both old and new jax.
-
-    ``jax.sharding.AxisType`` only exists from jax 0.5; older versions
-    treat every axis as Auto already, so the kwarg is simply omitted.
-    """
-    if hasattr(jax.sharding, "AxisType"):
-        return {"axis_types": (jax.sharding.AxisType.Auto,) * n}
-    return {}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """Single pod: 16×16 = 256 chips, (DATA_AXIS, MODEL_AXIS).
     Multi-pod: 2×16×16 = 512 chips, (POD_AXIS, DATA_AXIS, MODEL_AXIS)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = (POD_AXIS, DATA_AXIS, MODEL_AXIS) if multi_pod \
         else (DATA_AXIS, MODEL_AXIS)
-    return jax.make_mesh(shape, axes, **auto_axis_types(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_training_mesh(dp_degree: int, sp_degree: int, tp_degree: int = 1,
@@ -92,4 +82,5 @@ def make_test_mesh(shape=(2, 4), axes=(DATA_AXIS, SEQ_AXIS)):
 
     Defaults to the 2D DP×SP training mesh; the TP batteries pass
     ``axes=(DATA_AXIS, MODEL_AXIS)`` explicitly."""
-    return jax.make_mesh(shape, axes, **auto_axis_types(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))

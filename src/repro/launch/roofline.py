@@ -1,6 +1,9 @@
 import os
 os.environ.setdefault("XLA_FLAGS",
                       "--xla_force_host_platform_device_count=512")
+# A CPU tool that counts (virtual host devices); its per-cell children
+# inherit this, so on a TPU host none of them takes the chip.
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 import argparse          # noqa: E402
 import dataclasses      # noqa: E402

@@ -46,9 +46,11 @@ def main():
 
     from repro.checkpoint.manager import CheckpointManager
     from repro.configs import get_config, get_smoke
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import model as M
     from repro.serve.engine import ServeEngine
 
+    enable_compile_cache()
     cfg = get_smoke(args.arch) if args.smoke \
         else get_config(args.arch, linearize=args.linearize)
     key = jax.random.PRNGKey(0)

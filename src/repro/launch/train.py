@@ -94,9 +94,11 @@ def main():
     from repro.configs import get_config, get_smoke, get_variant
     from repro.configs.base import RunConfig
     from repro.data.pipeline import SyntheticLM
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.sharding.rules import make_plan
     from repro.train.loop import train
 
+    enable_compile_cache()
     if args.smoke:
         cfg = get_smoke(args.arch)
     elif args.variant:
@@ -143,9 +145,9 @@ def main():
                          backend=run.kernel_backend,
                          comm=run.comm_spec(), zero1=run.zero1)
     elif args.multi_device and len(jax.devices()) > 1:
-        from repro.launch.mesh import DATA_AXIS, auto_axis_types
+        from repro.launch.mesh import DATA_AXIS
         mesh = jax.make_mesh((len(jax.devices()),), (DATA_AXIS,),
-                             **auto_axis_types(1))
+                             axis_types=(jax.sharding.AxisType.Auto,))
         plan = make_plan(mesh, "train", global_batch=args.batch,
                          n_kv_heads=cfg.n_kv_heads,
                          backend=run.kernel_backend,

@@ -29,7 +29,7 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
 
-from repro.launch.hlo_analysis import PEAK_FLOPS
+from repro.launch.hlo_analysis import device_peak_flops
 from repro.obs.metrics import Histogram, MetricsSink, as_sink
 
 
@@ -79,8 +79,9 @@ class FlightRecorder:
         ``launch.roofline.model_flops`` with the run's shape); enables
         achieved-FLOP/s + MFU fields on step records.
     n_devices: devices the program spans (MFU denominator).
-    peak_flops: per-device peak (default: the roofline's TPU v5e bf16
-        constant — MFU is then "fraction of the machine we target").
+    peak_flops: per-device peak (default: the local device's entry in
+        ``launch.hlo_analysis.DEVICE_PEAKS``; a device not in that table
+        gets ``mfu: None``).
     wall_factor / wall_window / wall_warmup: rolling-median step-wall
         drift detection; the first ``wall_warmup`` steps (compile /
         resume spikes) are excluded from the window and never flagged.
@@ -88,13 +89,14 @@ class FlightRecorder:
 
     def __init__(self, sink: Optional[MetricsSink] = None, *,
                  model_flops_per_step: Optional[float] = None,
-                 n_devices: int = 1, peak_flops: float = PEAK_FLOPS,
+                 n_devices: int = 1, peak_flops: Optional[float] = None,
                  wall_factor: float = 3.0, wall_window: int = 50,
                  wall_warmup: int = 1):
         self.sink = as_sink(sink)
         self.model_flops_per_step = model_flops_per_step
         self.n_devices = max(int(n_devices), 1)
-        self.peak_flops = peak_flops
+        self.peak_flops = peak_flops if peak_flops is not None \
+            else device_peak_flops()
         self.wall_factor = wall_factor
         self.wall_window = wall_window
         self.wall_warmup = wall_warmup
@@ -220,7 +222,8 @@ class FlightRecorder:
         if self.model_flops_per_step and wall_s > 0:
             achieved = self.model_flops_per_step / wall_s
             rec["achieved_flops"] = achieved
-            rec["mfu"] = achieved / (self.peak_flops * self.n_devices)
+            rec["mfu"] = achieved / (self.peak_flops * self.n_devices) \
+                if self.peak_flops else None
         if self.snapshot is not None:
             rec["expected_collective_bytes"] = \
                 self.snapshot.expected_bytes_per_step

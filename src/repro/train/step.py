@@ -36,7 +36,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.flatten_util import ravel_pytree
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.compat import shard_map as _shard_map
 
@@ -54,6 +54,22 @@ MOE_AUX_COEF = 0.01
 
 def init_state(key, cfg: ModelConfig, run: RunConfig,
                plan: Optional[Parallelism] = None):
+    """The train state. On a manual (DP×SP) plan it is built in place on
+    every device of the mesh: an eager init lands on one device, where
+    its replicated copy would double that device's share (8.7 GB for a
+    4-layer full-width Linear-Llama3 state)."""
+    if plan is not None and plan.manual_axes:
+        def init(key_):
+            return _init_state(key_, cfg, run, plan)
+        shardings = jax.tree.map(
+            lambda s: NamedSharding(plan.mesh, s),
+            _state_specs(jax.eval_shape(init, key), plan))
+        return jax.jit(init, out_shardings=shardings)(key)
+    return _init_state(key, cfg, run, plan)
+
+
+def _init_state(key, cfg: ModelConfig, run: RunConfig,
+                plan: Optional[Parallelism]):
     params = M.init_params(key, cfg)
     if run.bf16_params:
         # §Perf: bf16 weight storage — halves FSDP gather traffic and
@@ -165,6 +181,17 @@ def _local_objective_fn(cfg: ModelConfig, run: RunConfig, plan: Parallelism):
         return obj, (ce_sum, n)
 
     return objective
+
+
+def _state_specs(state, plan: Parallelism):
+    """PartitionSpecs of the train state on a manual plan's mesh: params
+    and counters replicated, ZeRO-1 Adam moments sharded over the
+    optimizer-shard axes."""
+    sspec = jax.tree.map(lambda _: P(), state)
+    if plan.zero1_axis is not None:
+        sspec["opt"] = adamw.Zero1AdamState(
+            m=P(plan.zero1_axis), v=P(plan.zero1_axis), count=P())
+    return sspec
 
 
 def _make_manual_train_step(cfg: ModelConfig, run: RunConfig,
@@ -327,10 +354,7 @@ def _make_manual_train_step(cfg: ModelConfig, run: RunConfig,
         # plans — sequence-major, matching SPConfig.exchange_axes.
         token_ax = seq_ax if tp_ax is None else (seq_ax, tp_ax)
         bspec = jax.tree.map(lambda _: P(None, dp_ax, token_ax), batch)
-        sspec = jax.tree.map(lambda _: P(), state)
-        if zero_ax is not None:
-            sspec["opt"] = adamw.Zero1AdamState(
-                m=P(zero_ax), v=P(zero_ax), count=P())
+        sspec = _state_specs(state, plan)
         mspec = {"loss": P(), "grad_norm": P(), "lr": P(), "skipped": P()}
         if run.guard:
             mspec.update({key: P() for key in health.GUARD_METRICS})

@@ -418,8 +418,7 @@ def _():
                       cfg_override=get_smoke("hymba-1.5b"))
     compiled = cell.lower().compile()
     assert compiled.memory_analysis() is not None
-    from repro.core.compat import cost_analysis
-    assert cost_analysis(compiled).get("flops", 0) > 0
+    assert compiled.cost_analysis().get("flops", 0) > 0
 
 
 @check("hybrid (LASP-2H) train step == flash custom_vjp == xla backend")

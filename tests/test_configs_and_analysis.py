@@ -196,3 +196,87 @@ HloModule m
     assert counts[("all-gather", (SEQ_AXIS,))] == 1
     assert counts[("all-reduce", (DATA_AXIS, SEQ_AXIS))] == 1
     assert counts[("all-gather", (DATA_AXIS,))] == 1
+
+
+# Shapes of TPU compiled HLO: one async all-gather split into start /
+# update / done fusion computations sharing a channel_id, a synchronous
+# one, a reduce-scatter written as an all-reduce-scatter fusion, and ring
+# permutes that all carry channel_id=1 in the entry computation.
+_TPU_HLO = """\
+%fused_computation.1 (param_0.1: f32[4,8]) -> (f32[4,8], f32[16,8]) {
+  %all-gather.11 = f32[16,8]{1,0} all-gather(%param_0.1), channel_id=3, replica_groups={{0,1,2,3}}, dimensions={0}
+}
+%async_collective_fusion.1 (param_0.2: f32[4,8]) -> f32[16,8] {
+  %all-gather.13 = f32[16,8]{1,0} all-gather(%param_0.2), channel_id=3, replica_groups={{0,1,2,3}}, dimensions={0}
+}
+%fused_computation.2 (param_0.3: f32[4,8]) -> f32[16,8] {
+  %all-gather.15 = f32[16,8]{1,0} all-gather(%param_0.3), channel_id=3, replica_groups={{0,1,2,3}}, dimensions={0}
+}
+%all-reduce-scatter (input: f32[16,8]) -> f32[4,8] {
+  %all-reduce.7 = f32[16,8]{1,0} all-reduce(%input), channel_id=5, replica_groups={{0,1,2,3}}, to_apply=%add
+}
+ENTRY %main (p: f32[4,8]) -> f32[16,8] {
+  %all-gather.16 = f32[16,8]{1,0} all-gather(%p), channel_id=2, replica_groups={{0,1,2,3}}, dimensions={0}
+  %collective-permute-start = (f32[4,8], f32[4,8]) collective-permute-start(%p), channel_id=1, source_target_pairs={{0,1},{1,2},{2,3},{3,0}}
+  %collective-permute-start.1 = (f32[4,8], f32[4,8]) collective-permute-start(%p), channel_id=1, source_target_pairs={{0,1},{1,2},{2,3},{3,0}}
+  %psum.7 = f32[64]{0} all-reduce(%c), channel_id=1, replica_groups={{0,1,2,3}}, to_apply=%add
+}
+"""
+
+
+def test_tpu_async_collectives_counted_once():
+    counts = H.collective_counts(_TPU_HLO, 4)
+    assert counts == {"all-gather": 2, "reduce-scatter": 1,
+                      "all-reduce": 1, "collective-permute": 2}
+    (rs,) = [c for c in H.parse_collectives(_TPU_HLO, 4)
+             if c.op == "reduce-scatter"]
+    assert rs.result_bytes == 4 * 8 * 4          # the scattered piece
+
+
+def test_tpu_kernels_named_from_metadata():
+    hlo = "\n".join(
+        f'  %x.{i} = f32[8]{{0}} custom-call(%a), custom_call_target='
+        f'"tpu_custom_call", metadata={{op_name="{name}/pallas_call"}}'
+        for i, name in enumerate([
+            "jit(f)/lasp2_chunk_fwd",
+            "jit(f)/transpose(jvp(flash_attention_bwd_dq))",
+            "jit(f)/jvp(flash_attention_fwd)",
+            "jit(f)/jvp(flash_attention_fwd)"]))
+    hlo += '\n  %y = f32[8]{0} custom-call(%a), custom_call_target="other"'
+    assert H.tpu_kernels(hlo) == {"lasp2_chunk_fwd": 1,
+                                  "flash_attention_bwd_dq": 1,
+                                  "flash_attention_fwd": 2}
+
+
+def test_device_peak_flops_keyed_by_kind():
+    import jax
+
+    class Dev:
+        device_kind = "TPU v5 lite"
+
+    assert H.device_peak_flops(Dev()) == 197e12
+    assert H.device_peak_flops(jax.devices()[0]) is None   # the CPU
+
+
+@pytest.mark.parametrize("env", [None, "/cache/from/env"])
+def test_compile_cache_dir(monkeypatch, env):
+    import jax
+
+    from repro.launch import compile_cache
+
+    prev = jax.config.jax_compilation_cache_dir
+    if env is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env)
+    try:
+        got = compile_cache.enable_compile_cache()
+        want = env or str(compile_cache.CHECKOUT_CACHE_DIR)
+        assert got == want
+        # JAX's own reading of the variable stands: no path is set in code
+        assert jax.config.jax_compilation_cache_dir == (
+            prev if env else want)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+    assert compile_cache.CHECKOUT_CACHE_DIR.parent.joinpath(
+        "chip_smoke.py").exists()

@@ -90,8 +90,9 @@ def test_checkpoint_elastic_reshard(tmp_path):
     mgr = CheckpointManager(str(tmp_path))
     tree = {"w": jnp.arange(16.0).reshape(4, 4)}
     mgr.save(3, tree)
-    from repro.launch.mesh import DATA_AXIS, auto_axis_types
-    mesh = jax.make_mesh((1,), (DATA_AXIS,), **auto_axis_types(1))
+    from repro.launch.mesh import DATA_AXIS
+    mesh = jax.make_mesh((1,), (DATA_AXIS,),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     sh = {"w": NamedSharding(mesh, P(DATA_AXIS, None))}
     out = mgr.restore(3, jax.tree.map(jnp.zeros_like, tree), shardings=sh)
     np.testing.assert_array_equal(out["w"], tree["w"])
