@@ -32,6 +32,7 @@ from repro.core.lasp2h import (allgather_context_attention,
 from repro.kernels import ops
 from repro.models.layers import dense_init, mlp_apply, mlp_init, normal, \
     rmsnorm, rmsnorm_init, rope
+from repro.obs.scopes import scope
 from repro.sharding.rules import Parallelism
 
 
@@ -700,20 +701,23 @@ def layer_apply(params, x, ctx: Ctx, spec: LayerSpec):
     mix_apply = {"softmax": softmax_apply, "linear": linear_apply,
                  "mamba2": mamba2_apply, "hymba": hymba_apply,
                  "cross": cross_apply}[spec.mixer]
-    h = rmsnorm(params["ln1"], x, ctx.cfg.norm_eps)
-    if spec.mixer == "softmax":
-        y = mix_apply(params["mixer"], h, ctx, window=spec.sliding_window)
-    else:
-        y = mix_apply(params["mixer"], h, ctx)
+    with scope(f"mixer.{spec.mixer}"):
+        h = rmsnorm(params["ln1"], x, ctx.cfg.norm_eps)
+        if spec.mixer == "softmax":
+            y = mix_apply(params["mixer"], h, ctx,
+                          window=spec.sliding_window)
+        else:
+            y = mix_apply(params["mixer"], h, ctx)
     x = x + y
     aux = 0.0
     if "mlp" in params:
-        h = rmsnorm(params["ln2"], x, ctx.cfg.norm_eps)
-        if spec.mlp == "moe":
-            y, aux = moe_apply(params["mlp"], h, ctx)
-        else:
-            y = mlp_apply(params["mlp"], h, ctx.plan,
-                          act=getattr(ctx.cfg, "mlp_act", "swiglu"))
+        with scope("mlp"):
+            h = rmsnorm(params["ln2"], x, ctx.cfg.norm_eps)
+            if spec.mlp == "moe":
+                y, aux = moe_apply(params["mlp"], h, ctx)
+            else:
+                y = mlp_apply(params["mlp"], h, ctx.plan,
+                              act=getattr(ctx.cfg, "mlp_act", "swiglu"))
         x = x + y
     x = ctx.plan.act(x, "batch", "residual_seq", None)
     return x, aux

@@ -22,6 +22,7 @@ from repro.models import blocks
 from repro.models.blocks import Ctx
 from repro.models.layers import (embed_init, embed_lookup, logits_out,
                                  rmsnorm, rmsnorm_init, sinusoidal_positions)
+from repro.obs.scopes import scope
 from repro.sharding.rules import Parallelism, local_plan
 
 
@@ -105,7 +106,8 @@ def _apply_stack(groups, x, ctx: Ctx, specs, flags=None, remat="full",
         body = jax.checkpoint(
             body, policy=jax.checkpoint_policies.checkpoint_dots)
     xs = tuple(groups) + ((flags,) if flags is not None else ())
-    x, auxs = jax.lax.scan(body, x, xs, unroll=True if unroll else 1)
+    with scope("layers"):
+        x, auxs = jax.lax.scan(body, x, xs, unroll=True if unroll else 1)
     return x, jnp.sum(auxs)
 
 
@@ -116,8 +118,9 @@ def forward(params, tokens, cfg: ModelConfig, plan: Optional[Parallelism]
     plan = plan or local_plan()
     dtype = jnp.dtype(cfg.dtype)
     b, s = tokens.shape
-    x = embed_lookup(params["embed"], tokens, dtype)
-    x = plan.act(x, "batch", "residual_seq", None)
+    with scope("embed"):
+        x = embed_lookup(params["embed"], tokens, dtype)
+        x = plan.act(x, "batch", "residual_seq", None)
     positions = jnp.arange(s)
     if plan.sp is not None and plan.sp.manual and plan.sp.degree > 1:
         # Inside the train step's fully-manual shard_map ``s`` is the
@@ -138,8 +141,9 @@ def forward(params, tokens, cfg: ModelConfig, plan: Optional[Parallelism]
               enc_out=enc_out, causal=causal, resets=resets)
     x, aux = _apply_stack(params["groups"], x, ctx, cfg.pattern,
                           flags=flags, remat=remat, unroll=unroll)
-    x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = logits_out(params["embed"], x, plan, cfg.vocab_size)
+    with scope("head"):
+        x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        logits = logits_out(params["embed"], x, plan, cfg.vocab_size)
     return logits, aux
 
 

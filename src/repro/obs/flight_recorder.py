@@ -188,10 +188,13 @@ class FlightRecorder:
                 tokens: Optional[int] = None,
                 phases: Optional[Dict[str, float]] = None,
                 metrics: Optional[Dict[str, float]] = None,
-                straggler: Optional[bool] = None) -> Dict[str, Any]:
+                straggler: Optional[bool] = None,
+                host: Optional[Dict[str, float]] = None) -> Dict[str, Any]:
         """Build + emit one ``step`` record; returns it.
 
         ``phases``: ``{"<name>_s": wall}`` from a ``PhaseTimer.flush()``.
+        ``host``: the step's GC pauses and compiles
+        (``HostWatch.delta()``).
         ``straggler``: an external verdict (the train loop's watchdog);
         if ``None``, the recorder's own rolling-median drift rule
         decides."""
@@ -201,6 +204,8 @@ class FlightRecorder:
             rec.update({k: float(v) for k, v in metrics.items()})
         if phases:
             rec.update({k: float(v) for k, v in phases.items()})
+        if host:
+            rec.update({k: float(v) for k, v in host.items()})
 
         expected = self.expected_wall_s()
         self._seen += 1

@@ -21,11 +21,13 @@ The observability layer turns every run into machine-readable telemetry
   across per-shard sinks. :class:`Metrics` bundles named counters,
   gauges, and histograms into one registry with a flat ``snapshot()``.
 
-* **Phase timers** — :func:`scoped_timer` wraps a block in
-  ``jax.named_scope`` (so device profiles attribute ops to the phase)
-  and measures HOST wall time with explicit ``block_until_ready``
-  fencing: the block registers its output via ``fence.set(x)`` and the
-  timer blocks on it before reading the clock, so async dispatch cannot
+* **Phase timers** — :func:`scoped_timer` wraps a block in a
+  ``jax.profiler.TraceAnnotation`` (a host span of the phase in a
+  profiler trace; device ops get their layer from the scopes of
+  ``repro.obs.scopes``, inside the traced program) and measures HOST
+  wall time with explicit ``block_until_ready`` fencing: the block
+  registers its output via ``fence.set(x)`` and the timer blocks on it
+  before reading the clock, so async dispatch cannot
   leak one phase's device time into the next. Everything here is
   host-side — instrumentation adds **no collectives and no device ops**
   to the traced program.
@@ -327,15 +329,17 @@ def scoped_timer(name: str, out: Dict[str, float], *,
                  clock=time.perf_counter):
     """Time a named phase into ``out[name]`` (seconds, accumulating).
 
-    The block runs inside ``jax.named_scope(name)`` so device traces
-    attribute its ops to the phase; on exit the timer blocks on whatever
-    the block registered via ``fence.set(...)`` — without the fence,
+    The block runs inside ``jax.profiler.TraceAnnotation(name)``, a host
+    span of the phase in a profiler trace (a ``named_scope`` here would
+    name nothing: the block calls programs compiled elsewhere); on exit
+    the timer blocks on whatever the block registered via
+    ``fence.set(...)`` — without the fence,
     jax's async dispatch would charge this phase's device time to
     whichever later phase first synchronizes.
     """
     import jax
     fence = Fence()
-    with jax.named_scope(name):
+    with jax.profiler.TraceAnnotation(name):
         t0 = clock()
         try:
             yield fence

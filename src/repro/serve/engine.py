@@ -161,7 +161,7 @@ class ServeEngine:
             finished += self._admit(batch)
         if self.sched.active:
             t0 = time.perf_counter()
-            with jax.named_scope("decode"):
+            with jax.profiler.TraceAnnotation("decode"):
                 logits, self._cache = self._decode(
                     self.params, jnp.asarray(self._tok), self._cache)
                 steps = np.array([len(r.tokens) if r is not None else 0
@@ -194,7 +194,7 @@ class ServeEngine:
 
     def _admit(self, batch: PrefillBatch) -> List[Request]:
         t0 = time.perf_counter()
-        with jax.named_scope("prefill"):
+        with jax.profiler.TraceAnnotation("prefill"):
             if self.bucket_lengths:
                 logits, small = self._prefill(
                     self.params, jnp.asarray(batch.prompts),

@@ -9,17 +9,20 @@
 * non-finite gradient steps are skipped inside the jitted step,
 * SIGTERM/KeyboardInterrupt → final checkpoint, clean exit (preemption),
 * optional telemetry (``sink=``, docs/observability.md): per-step phase
-  walls / tokens-per-s / MFU records plus a compile-time flight-recorder
+  walls / tokens-per-s / MFU records, the GC pauses and compiles of each
+  step (``repro.obs.HostWatch``), plus a compile-time flight-recorder
   snapshot of the comm tape vs the compiled HLO. With ``sink=None`` the
   loop runs the exact uninstrumented path — no tape, no AOT lowering, no
   extra host work per step.
+* each step is a ``StepTraceAnnotation("train")`` in a profiler trace,
+  its phases host spans ``data``, ``step`` and ``ckpt``.
 """
 
 from __future__ import annotations
 
 import signal
 import time
-from contextlib import nullcontext
+from contextlib import ExitStack, nullcontext
 from typing import Callable, Optional
 
 import jax
@@ -28,6 +31,7 @@ import numpy as np
 from repro.checkpoint.manager import CheckpointError, CheckpointManager
 from repro.configs.base import ModelConfig, RunConfig
 from repro.data.pipeline import SyntheticLM
+from repro.obs.host import HostWatch
 from repro.resilience.guard import GuardAbort
 from repro.sharding.rules import Parallelism
 from repro.train.step import init_state, make_train_step
@@ -73,9 +77,10 @@ def train(cfg: ModelConfig, run: RunConfig, data: SyntheticLM, *,
     ahead-of-time ONCE (the AOT result is also the HLO the flight
     recorder cross-validates the tape against — no second compile),
     (b) emits one ``step`` record per step with phase walls
-    (data/step/ckpt), tokens/s, MFU and expected-vs-compiled collective
-    bytes, and (c) turns resume/straggler/signal prints into structured
-    ``event`` records. The caller owns the sink's lifetime.
+    (data/step/ckpt), tokens/s, MFU, expected-vs-compiled collective
+    bytes and the step's GC pauses and compiles, and (c) turns
+    resume/straggler/signal prints into structured ``event`` records.
+    The caller owns the sink's lifetime.
     """
     # single-device default still honours the kernel-backend knob
     plan = plan or Parallelism(backend=run.kernel_backend)
@@ -159,26 +164,31 @@ def train(cfg: ModelConfig, run: RunConfig, data: SyntheticLM, *,
         stop["now"] = True
 
     old_handler = signal.signal(signal.SIGTERM, _sig)
+    hooks = ExitStack()
     try:
+        watch = hooks.enter_context(HostWatch()) \
+            if recorder is not None else None
         for step in range(start_step, total):
-            with phase("data"):
-                batch = data.microbatched(step, run.num_microbatches)
-            t0 = time.perf_counter()
-            with phase("step") as f:
-                state, metrics = step_fn(state, batch)
-                if f is not None:
-                    f.set(metrics)
-                metrics = {k: float(v) for k, v in metrics.items()}
-            dt = time.perf_counter() - t0
-            slow = watchdog.record(dt)
-            with phase("ckpt"):
-                if mgr is not None and (step + 1) % ckpt_every == 0:
-                    mgr.save_async(step + 1, state)
+            with jax.profiler.StepTraceAnnotation("train", step_num=step):
+                with phase("data"):
+                    batch = data.microbatched(step, run.num_microbatches)
+                t0 = time.perf_counter()
+                with phase("step") as f:
+                    state, metrics = step_fn(state, batch)
+                    if f is not None:
+                        f.set(metrics)
+                    metrics = {k: float(v) for k, v in metrics.items()}
+                dt = time.perf_counter() - t0
+                slow = watchdog.record(dt)
+                with phase("ckpt"):
+                    if mgr is not None and (step + 1) % ckpt_every == 0:
+                        mgr.save_async(step + 1, state)
             rec = None
             if recorder is not None:
                 rec = recorder.on_step(step, dt, tokens=tokens_per_step,
                                        phases=timer.flush(),
-                                       metrics=metrics, straggler=slow)
+                                       metrics=metrics, straggler=slow,
+                                       host=watch.delta())
             metrics["step"], metrics["dt"] = step, dt
             history.append(metrics)
             skipped_total += int(metrics.get("skipped", 0))
@@ -224,6 +234,7 @@ def train(cfg: ModelConfig, run: RunConfig, data: SyntheticLM, *,
             recorder.event("interrupt")
     finally:
         signal.signal(signal.SIGTERM, old_handler)
+        hooks.close()
         if mgr is not None:
             mgr.wait()
             mgr.save(int(state["step"]), state)

@@ -44,6 +44,7 @@ from repro.comm import primitives as comm_primitives
 from repro.configs.base import ModelConfig, RunConfig
 from repro.launch.mesh import POD_AXIS
 from repro.models import model as M
+from repro.obs.scopes import scope
 from repro.optim import adamw
 from repro.optim.compression import compress_sync_tree
 from repro.resilience import guard as health
@@ -103,7 +104,8 @@ def make_loss_fn(cfg: ModelConfig, run: RunConfig, plan: Parallelism):
         logits, aux = M.forward(params, micro["tokens"], cfg, plan,
                                 remat=run.remat, unroll=run.scan_unroll,
                                 resets=micro.get("resets"), **kwargs)
-        loss = M.lm_loss(logits, micro["labels"])
+        with scope("loss"):
+            loss = M.lm_loss(logits, micro["labels"])
         return loss + MOE_AUX_COEF * aux, loss
     return loss_fn
 
@@ -172,7 +174,8 @@ def _local_objective_fn(cfg: ModelConfig, run: RunConfig, plan: Parallelism):
         logits, aux = M.forward(params, micro["tokens"], cfg, plan,
                                 remat=run.remat, unroll=run.scan_unroll,
                                 resets=micro.get("resets"))
-        ce_sum, n_valid, _ = M.lm_loss_sum(logits, micro["labels"])
+        with scope("loss"):
+            ce_sum, n_valid, _ = M.lm_loss_sum(logits, micro["labels"])
         n = n_valid.astype(jnp.float32)
         # n-weighted aux: after global normalization this is the
         # token-weighted mean of the per-shard aux losses (== the global
@@ -252,85 +255,87 @@ def _make_manual_train_step(cfg: ModelConfig, run: RunConfig,
             # the concat to materialize twice (~5% more step bytes).
             local_bad = jnp.logical_not(jnp.all(jnp.isfinite(ces)))
             tail.append(local_bad.astype(jnp.float32))
-        packed = jnp.concatenate([flat, jnp.stack(tail)])
-        packed = comm_primitives.psum_packed(
-            packed, axes if len(axes) > 1 else axes[0], group_size=world,
-            tag="train.grads")
+        with scope("grad_reduce"):
+            packed = jnp.concatenate([flat, jnp.stack(tail)])
+            packed = comm_primitives.psum_packed(
+                packed, axes if len(axes) > 1 else axes[0],
+                group_size=world, tag="train.grads")
         k = len(tail)
         ce_tot = packed[-k]
         n_tot = jnp.maximum(packed[-k + 1], 1.0)  # all-masked batch → loss 0
         gflat = packed[:-k] / n_tot
 
-        gnorm = jnp.sqrt(jnp.sum(gflat * gflat))
-        if run.guard:
-            nonfinite = (packed[-1] > 0) \
-                | jnp.logical_not(jnp.isfinite(gnorm)) \
-                | jnp.logical_not(jnp.isfinite(ce_tot)) \
-                | health.chaos_hit(state["step"], run.chaos_skip_steps)
-            scale, finite, new_guard, ginfo = health.guard_verdict(
-                state["guard"], gnorm, nonfinite,
-                grad_clip=run.grad_clip,
-                spike_factor=run.guard_spike_factor)
-            # where (not scale·0): NaN grads must not propagate as NaN·0
-            gflat = jnp.where(finite, gflat * scale, 0.0)
-        else:
-            scale = jnp.minimum(
-                1.0, run.grad_clip / jnp.maximum(gnorm, 1e-9))
-            finite = jnp.isfinite(gnorm)
-            # Fault tolerance: a non-finite step is skipped, not applied.
-            gflat = jnp.where(finite, gflat * scale, 0.0)
-        lr = adamw.cosine_schedule(
-            state["step"], base_lr=run.learning_rate,
-            warmup_steps=run.warmup_steps, total_steps=run.total_steps,
-            min_lr=run.min_lr)
+        with scope("optimizer"):
+            gnorm = jnp.sqrt(jnp.sum(gflat * gflat))
+            if run.guard:
+                nonfinite = (packed[-1] > 0) \
+                    | jnp.logical_not(jnp.isfinite(gnorm)) \
+                    | jnp.logical_not(jnp.isfinite(ce_tot)) \
+                    | health.chaos_hit(state["step"], run.chaos_skip_steps)
+                scale, finite, new_guard, ginfo = health.guard_verdict(
+                    state["guard"], gnorm, nonfinite,
+                    grad_clip=run.grad_clip,
+                    spike_factor=run.guard_spike_factor)
+                # where (not scale·0): NaN grads must not propagate as NaN·0
+                gflat = jnp.where(finite, gflat * scale, 0.0)
+            else:
+                scale = jnp.minimum(
+                    1.0, run.grad_clip / jnp.maximum(gnorm, 1e-9))
+                finite = jnp.isfinite(gnorm)
+                # Fault tolerance: a non-finite step is skipped, not applied.
+                gflat = jnp.where(finite, gflat * scale, 0.0)
+            lr = adamw.cosine_schedule(
+                state["step"], base_lr=run.learning_rate,
+                warmup_steps=run.warmup_steps, total_steps=run.total_steps,
+                min_lr=run.min_lr)
 
-        opt = state["opt"]
-        if zero_ax is not None:
-            # ZeRO-1: update this rank's 1/zero_deg flat slice, gather
-            # params. On 3D plans ``zero_ax`` is the combined
-            # (data, model) tuple — ``multi_axis_index`` linearizes it in
-            # the same major-first order the all-gather concatenates.
-            pflat, unravel_params = ravel_pytree(params)
-            n_params = pflat.size
-            padded = adamw.zero1_padded_size(params, zero_deg)
-            shard = padded // zero_deg
-            pad = padded - n_params
+            opt = state["opt"]
+            if zero_ax is not None:
+                # ZeRO-1: update this rank's 1/zero_deg flat slice, gather
+                # params. On 3D plans ``zero_ax`` is the combined
+                # (data, model) tuple — ``multi_axis_index`` linearizes it in
+                # the same major-first order the all-gather concatenates.
+                pflat, unravel_params = ravel_pytree(params)
+                n_params = pflat.size
+                padded = adamw.zero1_padded_size(params, zero_deg)
+                shard = padded // zero_deg
+                pad = padded - n_params
 
-            def padded_slice(vec):
-                vec = jnp.concatenate(
-                    [vec.astype(jnp.float32),
-                     jnp.zeros((pad,), jnp.float32)])
-                ix = comm_primitives.multi_axis_index(zero_ax) * shard
-                return jax.lax.dynamic_slice(vec, (ix,), (shard,))
+                def padded_slice(vec):
+                    vec = jnp.concatenate(
+                        [vec.astype(jnp.float32),
+                         jnp.zeros((pad,), jnp.float32)])
+                    ix = comm_primitives.multi_axis_index(zero_ax) * shard
+                    return jax.lax.dynamic_slice(vec, (ix,), (shard,))
 
-            g_sh = padded_slice(gflat)
-            p_sh = padded_slice(pflat)
-            d_sh = padded_slice(adamw.decay_mask(params))
-            count = opt.count + 1
-            new_p_sh, new_m, new_v = adamw.zero1_update_shard(
-                g_sh, opt.m, opt.v, p_sh, d_sh, count, lr=lr,
-                b1=run.adam_b1, b2=run.adam_b2,
-                weight_decay=run.weight_decay)
-            new_p_sh = jnp.where(finite, new_p_sh, p_sh)
-            new_m = jnp.where(finite, new_m, opt.m)
-            new_v = jnp.where(finite, new_v, opt.v)
-            count = jnp.where(finite, count, opt.count)
-            # ZeRO-1's all-gather-on-update: the only other collective
-            # touching the data axis.
-            gathered = comm_primitives.allgather_states(
-                new_p_sh, zero_ax, axis_size=zero_deg, gather_axis=0,
-                tiled=True, tag="zero1.param_gather")
-            new_params = unravel_params(gathered[:n_params])
-            new_opt = adamw.Zero1AdamState(new_m, new_v, count)
-        else:
-            grads_tree = unravel_grads(gflat)
-            new_params, new_opt = adamw.update(
-                grads_tree, opt, params, lr=lr, b1=run.adam_b1,
-                b2=run.adam_b2, weight_decay=run.weight_decay)
-            new_params = jax.tree.map(
-                lambda nw, o: jnp.where(finite, nw, o), new_params, params)
-            new_opt = jax.tree.map(
-                lambda nw, o: jnp.where(finite, nw, o), new_opt, opt)
+                g_sh = padded_slice(gflat)
+                p_sh = padded_slice(pflat)
+                d_sh = padded_slice(adamw.decay_mask(params))
+                count = opt.count + 1
+                new_p_sh, new_m, new_v = adamw.zero1_update_shard(
+                    g_sh, opt.m, opt.v, p_sh, d_sh, count, lr=lr,
+                    b1=run.adam_b1, b2=run.adam_b2,
+                    weight_decay=run.weight_decay)
+                new_p_sh = jnp.where(finite, new_p_sh, p_sh)
+                new_m = jnp.where(finite, new_m, opt.m)
+                new_v = jnp.where(finite, new_v, opt.v)
+                count = jnp.where(finite, count, opt.count)
+                # ZeRO-1's all-gather-on-update: the only other collective
+                # touching the data axis.
+                gathered = comm_primitives.allgather_states(
+                    new_p_sh, zero_ax, axis_size=zero_deg, gather_axis=0,
+                    tiled=True, tag="zero1.param_gather")
+                new_params = unravel_params(gathered[:n_params])
+                new_opt = adamw.Zero1AdamState(new_m, new_v, count)
+            else:
+                grads_tree = unravel_grads(gflat)
+                new_params, new_opt = adamw.update(
+                    grads_tree, opt, params, lr=lr, b1=run.adam_b1,
+                    b2=run.adam_b2, weight_decay=run.weight_decay)
+                new_params = jax.tree.map(
+                    lambda nw, o: jnp.where(finite, nw, o), new_params, params)
+                new_opt = jax.tree.map(
+                    lambda nw, o: jnp.where(finite, nw, o), new_opt, opt)
 
         new_state = {"params": new_params, "opt": new_opt,
                      "step": state["step"] + 1}
@@ -408,35 +413,36 @@ def make_train_step(cfg: ModelConfig, run: RunConfig, plan: Parallelism):
             grads = jax.tree.map(
                 lambda g: jnp.where(bad, jnp.full_like(g, jnp.nan), g),
                 grads)
-        if run.guard:
-            gnorm = adamw.global_norm(grads)
-            nonfinite = jnp.logical_not(jnp.isfinite(gnorm)) \
-                | jnp.logical_not(jnp.isfinite(ce)) \
-                | health.chaos_hit(state["step"], run.chaos_skip_steps)
-            gscale, finite, new_guard, ginfo = health.guard_verdict(
-                state["guard"], gnorm, nonfinite,
-                grad_clip=run.grad_clip,
-                spike_factor=run.guard_spike_factor)
-            grads = jax.tree.map(
-                lambda g: jnp.where(finite, g * gscale, jnp.zeros_like(g)),
-                grads)
-        else:
-            grads, gnorm = adamw.clip_by_global_norm(grads, run.grad_clip)
-            finite = jnp.isfinite(gnorm)
-            # Fault tolerance: a non-finite step is skipped, not applied.
-            grads = jax.tree.map(
-                lambda g: jnp.where(finite, g, jnp.zeros_like(g)), grads)
-        lr = adamw.cosine_schedule(
-            state["step"], base_lr=run.learning_rate,
-            warmup_steps=run.warmup_steps, total_steps=run.total_steps,
-            min_lr=run.min_lr)
-        new_params, new_opt = adamw.update(
-            grads, state["opt"], params, lr=lr, b1=run.adam_b1,
-            b2=run.adam_b2, weight_decay=run.weight_decay)
-        new_params = jax.tree.map(
-            lambda n, o: jnp.where(finite, n, o), new_params, params)
-        new_opt = jax.tree.map(
-            lambda n, o: jnp.where(finite, n, o), new_opt, state["opt"])
+        with scope("optimizer"):
+            if run.guard:
+                gnorm = adamw.global_norm(grads)
+                nonfinite = jnp.logical_not(jnp.isfinite(gnorm)) \
+                    | jnp.logical_not(jnp.isfinite(ce)) \
+                    | health.chaos_hit(state["step"], run.chaos_skip_steps)
+                gscale, finite, new_guard, ginfo = health.guard_verdict(
+                    state["guard"], gnorm, nonfinite,
+                    grad_clip=run.grad_clip,
+                    spike_factor=run.guard_spike_factor)
+                grads = jax.tree.map(
+                    lambda g: jnp.where(finite, g * gscale, jnp.zeros_like(g)),
+                    grads)
+            else:
+                grads, gnorm = adamw.clip_by_global_norm(grads, run.grad_clip)
+                finite = jnp.isfinite(gnorm)
+                # Fault tolerance: a non-finite step is skipped, not applied.
+                grads = jax.tree.map(
+                    lambda g: jnp.where(finite, g, jnp.zeros_like(g)), grads)
+            lr = adamw.cosine_schedule(
+                state["step"], base_lr=run.learning_rate,
+                warmup_steps=run.warmup_steps, total_steps=run.total_steps,
+                min_lr=run.min_lr)
+            new_params, new_opt = adamw.update(
+                grads, state["opt"], params, lr=lr, b1=run.adam_b1,
+                b2=run.adam_b2, weight_decay=run.weight_decay)
+            new_params = jax.tree.map(
+                lambda n, o: jnp.where(finite, n, o), new_params, params)
+            new_opt = jax.tree.map(
+                lambda n, o: jnp.where(finite, n, o), new_opt, state["opt"])
         new_state = {"params": new_params, "opt": new_opt,
                      "step": state["step"] + 1}
         if new_err is not None:

@@ -340,7 +340,8 @@ def test_train_sink_records_and_aot_parity(tmp_path):
     for r in steps:
         assert {"step_s", "data_s", "ckpt_s", "wall_s", "loss",
                 "tokens_per_s", "mfu", "straggler",
-                "expected_collective_bytes"} <= set(r)
+                "expected_collective_bytes", "gc_pauses", "gc_s",
+                "compiles", "compile_s"} <= set(r)
         assert r["tokens"] == 4 * 64
     assert steps[0]["straggler"] is False, "compile step never flagged"
     (summ,) = sink.by_kind("summary")
