@@ -65,7 +65,9 @@ def linear_attention_op(q, k, v, log_a=None, *, block_size: int = 128,
     # degenerating toward 1-token blocks, right-pad to the next block
     # multiple: zero k/v rows add nothing to the state and log_a = 0
     # leaves the decay product alone, so outputs (sliced back to S),
-    # final state, and log decay are exact.
+    # final state, and log decay are exact. The Pallas kernel then runs
+    # several chunks a grid step, in tiles it picks from S alone
+    # (``lasp2_chunk.seq_tile``).
     bs = pick_block(s, block_size)
     if bs != s and bs % 32:
         bs = min(block_size, s)

@@ -10,6 +10,7 @@ inside a fixture, never at import: only one process at a time may load
 the TPU library.
 """
 
+import functools
 import os
 
 import jax
@@ -23,6 +24,7 @@ from repro.kernels.lasp2_decode import lasp2_decode_step
 from repro.launch.hlo_analysis import tpu_kernels
 
 BH, S, D = 16, 4096, 128        # Linear-Llama3-1B: 16 heads of 128, 4k ctx
+S_TRAIN = 8192                  # the linear-train-8k row
 WINDOW = 2048                   # the 1/4 hybrid's softmax window
 SLOTS = 4                       # decode batch
 ODD = 100                       # a prompt length off every block multiple
@@ -48,20 +50,20 @@ def one_chip():
     jax.config.update("jax_enable_compilation_cache", prev)
 
 
-def _chunk_fwd(sds):
-    q = sds((BH, S, D))
+def _chunk_fwd(sds, s=S):
+    q = sds((BH, s, D))
     return (lambda q_, k_, v_, la_: lasp2_chunk_fwd(q_, k_, v_, la_),
-            (q, q, q, sds((BH, S), jnp.float32)))
+            (q, q, q, sds((BH, s), jnp.float32)))
 
 
-def _chunk_grad(sds):
+def _chunk_grad(sds, s=S):
     def loss(q_, k_, v_, la_):
         o, st, ld = lasp2_chunk(q_, k_, v_, la_)
         return (jnp.sum(o.astype(jnp.float32)) + jnp.sum(st)
                 + jnp.sum(ld))
-    q = sds((BH, S, D))
+    q = sds((BH, s, D))
     return (jax.grad(loss, argnums=(0, 1, 2, 3)),
-            (q, q, q, sds((BH, S), jnp.float32)))
+            (q, q, q, sds((BH, s), jnp.float32)))
 
 
 def _decode(sds):
@@ -111,6 +113,17 @@ def _odd_prompt_grads(sds):
     return jax.grad(loss, argnums=(0, 1, 2)), (q, q, q)
 
 
+def _short_chunk_grads(sds):
+    """A prompt of three 64-token chunks (S 192, no 128-token divisor):
+    one tile holds the whole sequence, so the (1, 1, T) log-decay rows
+    span the array."""
+    def loss(q_, k_, v_):
+        o, st, _ = ops.linear_attention_op(q_, k_, v_, backend="pallas")
+        return jnp.sum(o.astype(jnp.float32)) + jnp.sum(st)
+    q = sds((SLOTS, BH, 192, D))
+    return jax.grad(loss, argnums=(0, 1, 2)), (q, q, q)
+
+
 _FLASH = {"flash_attention_fwd", "flash_attention_bwd_dq",
           "flash_attention_bwd_dkv"}
 CASES = {
@@ -118,10 +131,18 @@ CASES = {
     "lasp2_chunk grad": (_chunk_grad, {"lasp2_chunk_fwd",
                                        "lasp2_chunk_bwd_dq",
                                        "lasp2_chunk_bwd_dkv"}),
+    # the linear-train-8k row: tiles of several chunks a grid step
+    "lasp2_chunk fwd 8k": (functools.partial(_chunk_fwd, s=S_TRAIN),
+                           {"lasp2_chunk_fwd"}),
+    "lasp2_chunk grad 8k": (functools.partial(_chunk_grad, s=S_TRAIN),
+                            {"lasp2_chunk_fwd", "lasp2_chunk_bwd_dq",
+                             "lasp2_chunk_bwd_dkv"}),
     "lasp2_decode_step": (_decode, {"lasp2_decode_step"}),
     "flash fwd": (_flash_fwd, {"flash_attention_fwd"}),
     "flash grad": (_flash_grad, _FLASH),
     "flash grad traced offset": (_flash_grad_traced_offset, _FLASH),
+    "three 64-token chunks grads": (_short_chunk_grads, {
+        "lasp2_chunk_fwd", "lasp2_chunk_bwd_dq", "lasp2_chunk_bwd_dkv"}),
     "odd prompt length grads": (_odd_prompt_grads, _FLASH | {
         "lasp2_chunk_fwd", "lasp2_chunk_bwd_dq", "lasp2_chunk_bwd_dkv"}),
 }

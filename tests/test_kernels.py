@@ -8,7 +8,8 @@ import pytest
 
 from repro.kernels import ops
 from repro.kernels.flash_attention import flash_attention
-from repro.kernels.lasp2_chunk import lasp2_chunk_fwd
+from repro.core.linear_attention import RESET_LOG_A
+from repro.kernels.lasp2_chunk import MAX_TILE, lasp2_chunk_fwd, seq_tile
 from repro.kernels.ref import flash_attention_ref, linear_attention_ref
 
 TOL = {jnp.float32: 3e-4, jnp.bfloat16: 4e-2}
@@ -16,7 +17,9 @@ GRAD_TOL = 1e-3
 
 
 @pytest.mark.parametrize("s,dk,dv", [(256, 64, 64), (512, 128, 128),
-                                     (256, 32, 64), (128, 128, 64)])
+                                     (256, 32, 64), (128, 128, 64),
+                                     (2048, 128, 128), (4096, 128, 128),
+                                     (6144, 128, 64)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("decay", [False, True])
 def test_lasp2_chunk_kernel_sweep(rng, s, dk, dv, dtype, decay):
@@ -185,18 +188,28 @@ def _op_loss(backend, cot, block_size=64):
     return loss
 
 
-@pytest.mark.parametrize("decay", [False, True])
+@pytest.mark.parametrize("decay", [False, True, "resets"])
 def test_lasp2_chunk_grads_match_chunk_scan_autodiff(rng, decay):
     """jax.grad through the Pallas custom_vjp (interpret) == XLA autodiff
     of chunk_scan, pulling on ALL THREE outputs (o, state, log_decay) —
     the faithful SP backward pulls on o and state; data-dependent decay
-    additionally needs d log_a."""
-    q, k, v, la_, cot = _grad_case(rng)
+    additionally needs d log_a. ``resets``: document starts (state resets)
+    on the first token of a tile, on a chunk boundary inside a tile and in
+    the middle of a chunk, over two tiles of several 128-token chunks."""
+    block = 64
+    if decay == "resets":
+        block, s = 128, 2 * MAX_TILE
+        tile = seq_tile(s, block)
+        assert block < tile < s
+        q, k, v, la_, cot = _grad_case(rng, s=s, dk=16, dv=16)
+        la_ = la_.at[..., [block, tile // 2 + 37, tile]].set(RESET_LOG_A)
+    else:
+        q, k, v, la_, cot = _grad_case(rng)
     if not decay:
         la_ = jnp.zeros_like(la_)
-    g_int = jax.grad(_op_loss("interpret", cot), argnums=(0, 1, 2, 3))(
-        q, k, v, la_)
-    g_xla = jax.grad(_op_loss("xla", cot), argnums=(0, 1, 2, 3))(
+    g_int = jax.grad(_op_loss("interpret", cot, block),
+                     argnums=(0, 1, 2, 3))(q, k, v, la_)
+    g_xla = jax.grad(_op_loss("xla", cot, block), argnums=(0, 1, 2, 3))(
         q, k, v, la_)
     for name, gi, gx in zip("q k v log_a".split(), g_int, g_xla):
         np.testing.assert_allclose(gi, gx, rtol=GRAD_TOL, atol=GRAD_TOL,
@@ -306,17 +319,93 @@ def test_flash_offset_matches_xla_mask(rng, sq, sk, window):
         assert float(jnp.max(jnp.abs(o2 - o_int))) > 1e-3
 
 
-def test_kernel_vmem_footprint_static():
-    """BlockSpec tiles must fit VMEM (16 MB/core budget, fp32 scratch)."""
-    bq, bk, dh, dkv = 128, 128, 128, 128
+def _vmem_bytes(shape, dtype):
+    """Bytes of a block in VMEM: the last two dims padded to the (8, 128)
+    tiling (16 sublanes for 16-bit types)."""
+    itemsize = np.dtype(dtype).itemsize
+    *lead, rows, lanes = shape
+    sub = 8 * 4 // itemsize
+    return (int(np.prod(lead)) * -(-rows // sub) * sub
+            * -(-lanes // 128) * 128 * itemsize)
+
+
+def test_kernel_vmem_footprint_static(monkeypatch):
+    """BlockSpec tiles must fit VMEM (16 MB/core budget, fp32 scratch).
+    The chunk kernels' footprint is read from their own ``pallas_call``s at
+    the ``linear-train-8k`` shape (B·H 16, S 8192, d 128, bf16), in bf16 and
+    in fp32 inputs: every in/out block double-buffered, plus scratch."""
+    from jax.experimental import pallas as pl
+
+    from repro.kernels.lasp2_chunk import lasp2_chunk
+
+    bq, bk, dh = 128, 128, 128
     flash_tiles = (bq * dh + 2 * bk * dh + bq * dh) * 4 + bq * dh * 4
-    chunk_tiles = (2 * 128 * dkv + 2 * 128 * dkv) * 4 + dkv * dkv * 4
     # flash bwd dkv pass: q/k/v/do tiles + lse/delta rows + 2 accumulators
     flash_bwd_tiles = (2 * bq * dh + 2 * bk * dh + 2 * bq) * 4 \
         + 2 * bk * dh * 4
     assert flash_tiles < 16 * 2 ** 20
-    assert chunk_tiles < 16 * 2 ** 20
     assert flash_bwd_tiles < 16 * 2 ** 20
+
+    calls = {}
+
+    def capture(kernel, *, grid, in_specs, out_specs, out_shape,
+                scratch_shapes=(), name=None, **kw):
+        def run(*operands):
+            outs = out_shape if isinstance(out_shape, list) else [out_shape]
+            blocks = ([(sp.block_shape, op.dtype)
+                       for sp, op in zip(in_specs, operands)]
+                      + [(sp.block_shape, o.dtype) for sp, o in zip(
+                          out_specs if isinstance(out_specs, list)
+                          else [out_specs], outs)])
+            calls[name, operands[0].dtype] = (grid, blocks, [
+                (sc.shape, sc.dtype) for sc in scratch_shapes])
+            zeros = [jnp.zeros(o.shape, o.dtype) for o in outs]
+            return zeros if isinstance(out_shape, list) else zeros[0]
+        return run
+
+    def loss(q, k, v, la_):
+        o, st, ld = lasp2_chunk(q, k, v, la_)
+        return jnp.sum(o.astype(jnp.float32)) + jnp.sum(st) + jnp.sum(ld)
+
+    bh, s, d = 16, 8192, 128
+    monkeypatch.setattr(pl, "pallas_call", capture)
+    jax.clear_caches()
+    try:
+        for dtype in (jnp.bfloat16, jnp.float32):
+            x = jax.ShapeDtypeStruct((bh, s, d), dtype)
+            jax.eval_shape(jax.grad(loss, argnums=(0, 1, 2, 3)), x, x, x,
+                           jax.ShapeDtypeStruct((bh, s), jnp.float32))
+    finally:
+        jax.clear_caches()      # drop the traces that hold the stand-in
+    tile = seq_tile(s, 128)
+    assert len(calls) == 6
+    for (name, dtype), (grid, blocks, scratch) in calls.items():
+        assert grid == (bh, s // tile), name
+        # sequence blocks are tiles of T tokens; the state is one block
+        assert all(tile in shape for shape, _ in blocks
+                   if shape != (1, d, d)), name
+        footprint = (2 * sum(_vmem_bytes(*b) for b in blocks)
+                     + sum(_vmem_bytes(*sc) for sc in scratch))
+        assert footprint < 16 * 2 ** 20, (name, dtype, footprint)
+
+
+def test_seq_tile_rule():
+    """Tokens per grid step of the chunk kernels, a static choice per
+    shape. At the ``linear-train-8k`` shape (S 8192, chunk 128) it is the
+    tile PERF.md records: 1024 tokens, 8 steps a row instead of 64."""
+    assert seq_tile(8192, 128) == 1024
+    # lengths the kernel is not given as such (ops pads them first): no
+    # multiple of the chunk divides them, so the tile is the chunk
+    for s in (129, 251):
+        assert seq_tile(s, 128) == 128
+    assert seq_tile(17, 17) == 17                 # a short prompt: one chunk
+    assert seq_tile(256, 128) == 256              # 129 and 251, padded
+    assert seq_tile(61 * 128, 128) == 128         # a prime chunk count
+    for c in (32, 64, 100, 128):
+        for s in range(c, 70 * c + 1, c):
+            t = seq_tile(s, c)
+            assert s % t == 0 and t % c == 0 and c <= t <= max(c, MAX_TILE)
+            assert t == c or t % 128 == 0 or t == s
 
 
 # ---------------------------------------------------------------------------
