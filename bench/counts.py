@@ -4,18 +4,11 @@ Model FLOPs are the work the model needs, not what the program runs:
 recomputation (remat) and padding are not counted, and the input
 embedding, a gather, is no matrix product.
 
-Per token and layer of a dense model with matrix parameters ``N`` a
-forward pass takes ``2N`` FLOPs and a backward ``4N``. The head is a
-matrix product over the vocabulary slice (``vocab_size · d``). Linear
-attention is counted by its chunked form with block ``C`` (per token and
-head, ``dk``/``dv`` the head widths):
-
-    scores  q kᵀ inside the block     2·C·dk
-    scores·v                          2·C·dv
-    q·M (state read)                  2·dk·dv
-    kᵀv (state update)                2·dk·dv
-
-so ``2·C·(dk+dv) + 4·dk·dv`` forward, and twice that backward.
+Per token, a layer's matrix products take ``2N`` FLOPs forward for its
+``N`` matrix parameters and ``4N`` backward; the head is a matrix product
+over the vocabulary slice (``vocab_size · d``). What a mixer computes
+beyond its products (the scores and states of attention) its kind counts
+(``mixing_flops`` in ``bench/kinds/<kind>.py``), twice that backward.
 
 Kernel counts (``kernel_work``) are per call of each Pallas kernel, from
 the shapes it is called with; their bytes are the least a call must move
@@ -25,45 +18,50 @@ once.
 
 from __future__ import annotations
 
+from bench import kinds
+
+
+def _layers(c: dict):
+    """The kind modules of every layer: (mixer, mlp) per pattern position,
+    and how often the pattern repeats."""
+    return [(kinds.kind(p["mixer"]), kinds.kind(p["mlp"]))
+            for p in kinds.pattern(c)], kinds.groups(c)
+
 
 def matmul_params(c: dict) -> int:
-    """Matrix parameters that every token multiplies: attention and MLP
-    projections of every layer plus the head over the vocabulary slice.
-    The input embedding is left out."""
-    d, f = c["hidden_size"], c["intermediate_size"]
-    hq = c["num_attention_heads"] * c["head_dim"]
-    hkv = c["num_key_value_heads"] * c["head_dim"]
-    per_layer = d * hq + 2 * d * hkv + hq * d + 3 * d * f
-    return c["num_hidden_layers"] * per_layer + c["vocab_size"] * d
+    """Matrix parameters that every token multiplies: the projections of
+    every layer's kinds plus the head over the vocabulary slice. The input
+    embedding is left out."""
+    pos, g = _layers(c)
+    return g * sum(m.matmul_params(c) + f.matmul_params(c) for m, f in pos) \
+        + c["vocab_size"] * c["hidden_size"]
 
 
-def linear_attention_flops_fwd(c: dict) -> int:
-    """Forward FLOPs per token of one linear-attention layer, chunked."""
-    C = c["linear_attention"]["block_size"]
-    dh, h = c["head_dim"], c["num_attention_heads"]
-    return h * (2 * C * (dh + dh) + 4 * dh * dh)
+def _mixing(c: dict, seq_len) -> int:
+    pos, g = _layers(c)
+    return g * sum(m.mixing_flops(c, seq_len) + f.mixing_flops(c, seq_len)
+                   for m, f in pos)
 
 
-def n_linear_layers(c: dict) -> int:
-    p = c["layer_pattern"]
-    return c["num_hidden_layers"] // len(p) * sum(m == "linear" for m in p)
+def forward_flops_per_token(c: dict, seq_len: int = None) -> int:
+    """Forward FLOPs per token in a row of ``seq_len`` tokens (a kind whose
+    count depends on it needs it)."""
+    return 2 * matmul_params(c) + _mixing(c, seq_len)
 
 
-def forward_flops_per_token(c: dict) -> int:
-    return 2 * matmul_params(c) + n_linear_layers(c) * \
-        linear_attention_flops_fwd(c)
-
-
-def train_flops_per_token(c: dict) -> int:
+def train_flops_per_token(c: dict, seq_len: int = None) -> int:
     """Forward and backward: three times the forward."""
-    return 3 * forward_flops_per_token(c)
+    return 3 * forward_flops_per_token(c, seq_len)
 
 
-def decode_flops_per_token(c: dict) -> int:
-    """One recurrent step per token: the matrix products and, per linear
-    layer and head, the state update and read (4·dk·dv)."""
-    dh, h = c["head_dim"], c["num_attention_heads"]
-    return 2 * matmul_params(c) + n_linear_layers(c) * h * 4 * dh * dh
+def decode_flops_per_token(c: dict, context: int = None) -> int:
+    """One decode step after ``context`` tokens: the matrix products and
+    each kind's recurrent work (for linear attention the state update and
+    read, 4·dk·dv a head)."""
+    pos, g = _layers(c)
+    return 2 * matmul_params(c) + g * sum(
+        m.decode_mixing_flops(c, context) + f.decode_mixing_flops(c, context)
+        for m, f in pos)
 
 
 # ---------------------------------------------------------------------------
@@ -122,5 +120,4 @@ def prefill_flops(c: dict, n: int) -> int:
     """A prompt of ``n`` tokens: every token through the layers, and the
     head once, for the last position."""
     head = c["vocab_size"] * c["hidden_size"]
-    return n * (2 * (matmul_params(c) - head) + n_linear_layers(c)
-                * linear_attention_flops_fwd(c)) + 2 * head
+    return n * (2 * (matmul_params(c) - head) + _mixing(c, n)) + 2 * head

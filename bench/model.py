@@ -10,6 +10,8 @@ from __future__ import annotations
 import json
 import os
 
+from bench import kinds
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -36,8 +38,9 @@ def model_config(c: dict):
         qkv_bias=c["qkv_bias"], rope_theta=c["rope_theta"],
         norm_eps=c["rms_norm_eps"],
         tie_embeddings=c["tie_word_embeddings"],
-        pattern=tuple(LayerSpec(mixer=m, mlp="dense")
-                      for m in c["layer_pattern"]),
+        pattern=tuple(LayerSpec(mixer=kinds.kind(p["mixer"]).PROGRAM,
+                                mlp=kinds.kind(p["mlp"]).PROGRAM)
+                      for p in kinds.pattern(c)),
         linear_attn=LinearAttnConfig(
             feature_map=la["feature_map"], decay=la["decay"],
             backward=la["backward"], block_size=la["block_size"]),

@@ -5,30 +5,23 @@ kernel, no cache, no batching, nothing imported from the program. Every
 matrix product runs at ``precision="highest"`` (on a TPU a float32 product
 is otherwise computed in bf16 passes).
 
-The architecture, per layer (pre-norm residual):
+The architecture, per layer (pre-norm residual), with the mixer and the
+MLP of the layer's kinds (``bench/kinds``, one module each):
 
-    h = rmsnorm(x) · ln1
-    q, k, v = h Wq + bq, h Wk + bk, h Wv + bv      (split into heads)
-    q, k = rope(q), rope(k);  q = q / sqrt(dh)
-    o_t = Σ_{s ≤ t, doc(s) = doc(t)} (q_t · k_s) v_s   (basic linear attention)
-    x = x + o Wo
-    h = rmsnorm(x) · ln2
-    x = x + (silu(h W1) ⊙ h W3) W2
+    x = x + mixer(rmsnorm(x) · ln1)
+    x = x + mlp(rmsnorm(x) · ln2)
 
 and ``logits = rmsnorm(x) · final_norm · lm_headᵀ`` over the vocabulary
-(the padded rows of the head are left out).
+(the padded rows of the head are left out). The pattern's positions are
+applied in order, each layer under ``jax.checkpoint``, and the pattern is
+scanned over its repeats. In a row longer than ``ROWS`` the MLP runs in
+blocks of rows under ``jax.checkpoint`` (the linear mixer in blocks of
+tokens with its state carried), and the loss always in blocks of
+``LOSS_ROWS``, so that a 65k-token row fits one chip.
 
-Departures from the published Qwen1.5-1.8B, all of them the paper's or
-the deployment's: softmax attention is replaced by linear attention
-(identity feature map, no decay, no normalisation) as the Linear-X recipe
-does; RoPE is applied to q and k before the linear attention, with the
-rotate-half convention and positions counted over the whole packed row;
-documents packed into a row do not see each other (the state is reset at
-each document start); the vocabulary is this chip's slice.
-
-Linear attention is computed in chunks of ``CHUNK`` tokens: a masked
-score matrix inside the chunk and a carried ``dk × dv`` state between
-chunks, so 65k-token rows fit. The loss is taken over blocks of rows.
+Departures from the published configurations, all of them the paper's
+or the deployment's, are noted in each kind; the vocabulary is this
+chip's slice.
 
 ``precision="fp8"`` is the control: every matrix product takes its two
 operands through float8 (e4m3, one scale per tensor) first, with the
@@ -42,8 +35,12 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from bench import kinds
+from bench.weights import layer_leaves, leaf_name
+
 CHUNK = 256
 LOSS_ROWS = 2048
+ROWS = 8192
 
 
 def _q8(x):
@@ -76,64 +73,34 @@ def rope(x, pos, theta):
                             x1 * jnp.sin(ang) + x2 * jnp.cos(ang)], -1)
 
 
-def linear_attention(q, k, v, seg, precision):
-    """o_t = Σ_{s ≤ t, seg_s = seg_t} (q_t·k_s) v_s. q, k: (S, H, dk);
-    v: (S, H, dv); seg: (S,) document ids. S is a multiple of CHUNK."""
-    s, h, dk = q.shape
-    dv = v.shape[-1]
-    n = s // CHUNK
-    qc, kc, vc = (t.reshape(n, CHUNK, h, t.shape[-1]) for t in (q, k, v))
-    sc = seg.reshape(n, CHUNK)
-    causal = jnp.tril(jnp.ones((CHUNK, CHUNK), bool))
-
-    def chunk(carry, xs):
-        m, seg_m = carry
-        qi, ki, vi, si = xs
-        mask = causal & (si[:, None] == si[None, :])
-        a = _ein("ihd,jhd->hij", qi, ki, precision) * mask
-        o = _ein("hij,jhd->ihd", a, vi, precision)
-        inter = _ein("ihk,hkv->ihv", qi, m, precision)
-        o = o + jnp.where((si == seg_m)[:, None, None], inter, 0.0)
-        last = si[-1]
-        kin = ki * (si == last)[:, None, None]
-        m = jnp.where(last == seg_m, m, 0.0) + _ein("jhk,jhv->hkv", kin, vi,
-                                                    precision)
-        return (m, last), o
-
-    m0 = jnp.zeros((h, dk, dv), jnp.float32)
-    _, o = jax.lax.scan(chunk, (m0, seg[0]), (qc, kc, vc, sc))
-    return o.reshape(s, h, dv)
-
-
-def _layer(c, precision, x, lw, pos, seg):
-    eps, theta = c["rms_norm_eps"], c["rope_theta"]
-    hq, hkv, dh = (c["num_attention_heads"], c["num_key_value_heads"],
-                   c["head_dim"])
+def _rows(f, x):
+    """``f`` of a position-wise part over the rows of ``x``: at once up to
+    ``ROWS`` rows, else in blocks of ``ROWS`` under ``jax.checkpoint``, so
+    that a long row's intermediates are held a block at a time."""
     s = x.shape[0]
-    mm = functools.partial(_ein, "sd,df->sf", precision=precision)
-    h = rmsnorm(x, lw["ln1"], eps)
-    q, k, v = mm(h, lw["wq"]), mm(h, lw["wk"]), mm(h, lw["wv"])
-    if c["qkv_bias"]:
-        q, k, v = q + lw["bq"], k + lw["bk"], v + lw["bv"]
-    q = rope(q.reshape(s, hq, dh), pos, theta) * dh ** -0.5
-    k = rope(k.reshape(s, hkv, dh), pos, theta)
-    v = v.reshape(s, hkv, dh)
-    if hkv != hq:
-        k, v = (jnp.repeat(t, hq // hkv, axis=1) for t in (k, v))
-    o = linear_attention(q, k, v, seg, precision).reshape(s, hq * dh)
-    x = x + mm(o, lw["wo"])
-    h = rmsnorm(x, lw["ln2"], eps)
-    return x + mm(jax.nn.silu(mm(h, lw["w1"])) * mm(h, lw["w3"]), lw["w2"])
+    if s <= ROWS:
+        return f(x)
+    n = -(-s // ROWS)
+    xb = jnp.pad(x, ((0, n * ROWS - s), (0, 0))).reshape(n, ROWS, -1)
+    return jax.lax.map(jax.checkpoint(f), xb).reshape(n * ROWS, -1)[:s]
 
 
-_LAYER = ("ln1", "wq", "bq", "wk", "bk", "wv", "bv", "wo", "ln2", "w1",
-          "w3", "w2")
+def _layer(c, spec, precision, x, lw, pos, seg):
+    """One layer: the mixer and the MLP of its kinds, each pre-norm with a
+    residual."""
+    eps = c["rms_norm_eps"]
+    mixer, mlp = kinds.kind(spec["mixer"]), kinds.kind(spec["mlp"])
+    x = x + mixer.apply(c, lw, rmsnorm(x, lw["ln1"], eps), pos, seg,
+                        precision)
+    if "ln2" not in lw:
+        return x
+    return _rows(lambda x_: x_ + mlp.apply(c, lw, rmsnorm(x_, lw["ln2"],
+                                                          eps), precision),
+                 x)
 
 
 def hidden(w, tokens, pos, seg, c, precision="fp32"):
     """Final normed hidden states (S, d) of one row, in float32."""
-    if any(m != "linear" for m in c["layer_pattern"]):
-        raise NotImplementedError("the reference holds linear layers only")
     s = tokens.shape[0]
     pad = -s % CHUNK
     if pad:   # causal: rows appended at the end change nothing before
@@ -142,9 +109,16 @@ def hidden(w, tokens, pos, seg, c, precision="fp32"):
         seg = jnp.pad(seg, (0, pad), constant_values=-1)
     f32 = lambda t: t.astype(jnp.float32)
     x = f32(w["embed"])[tokens]
-    layers = {n: f32(w[n]) for n in _LAYER if n in w}
-    body = jax.checkpoint(
-        lambda x_, lw: (_layer(c, precision, x_, lw, pos, seg), None))
+    specs = kinds.pattern(c)
+    layers = tuple({n: f32(w[leaf_name(c, p, n)]) for n in layer}
+                   for p, layer in enumerate(layer_leaves(c)))
+
+    def body(x_, lws):
+        for spec, lw in zip(specs, lws):
+            x_ = jax.checkpoint(lambda x1, lw1, spec=spec: _layer(
+                c, spec, precision, x1, lw1, pos, seg))(x_, lw)
+        return x_, None
+
     x, _ = jax.lax.scan(body, x, layers)
     return rmsnorm(x, f32(w["final_norm"]), c["rms_norm_eps"])[:s]
 

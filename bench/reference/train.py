@@ -16,8 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from bench.reference import model as R
-
-NO_DECAY = ("ln1", "ln2", "final_norm", "bq", "bk", "bv")
+from bench.weights import leaves
 
 
 def lr_at(step: int, o: dict) -> float:
@@ -38,6 +37,8 @@ def leaf_norms(flat: dict, scale=1.0) -> dict:
 
 def make_step(c: dict, precision: str):
     o = c["optimizer"]
+    no_decay = {n for n, (_, init) in leaves(c).items()
+                if init in ("norm", "bias")}
 
     def step(params, m, v, batch, lr, count):
         with jax.default_matmul_precision("highest"):
@@ -54,7 +55,7 @@ def make_step(c: dict, precision: str):
             new_m[n] = o["b1"] * m[n] + (1 - o["b1"]) * g[n]
             new_v[n] = o["b2"] * v[n] + (1 - o["b2"]) * g[n] * g[n]
             upd = (new_m[n] / bc1) / (jnp.sqrt(new_v[n] / bc2) + o["eps"])
-            if n not in NO_DECAY:
+            if n not in no_decay:
                 upd = upd + o["weight_decay"] * params[n]
             new_p[n] = params[n] - lr * upd
         return new_p, new_m, new_v, loss, leaf_norms(g)

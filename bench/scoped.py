@@ -268,11 +268,12 @@ def measure(record) -> dict | None:
     s = record["state"]
     first = s["next"] + record["window"]["steps"]
     batch = train._feed(ctx.traffic, ctx.seed, first,
-                        ctx.config["vocab_size"])
+                        ctx.config["vocab_size"], s.get("batch_sharding"))
     compiled = s["step"].lower(s["state"], batch).compile()
     hlo = compiled.as_text()
     scopes = _instruction_scopes(hlo)
-    run = {"state": s["state"], "step": compiled, "next": first}
+    run = {"state": s["state"], "step": compiled, "next": first,
+           "batch_sharding": s.get("batch_sharding")}
     tdir = os.path.join(ROOT, "bench", ".traces", f"{ctx.workload}.scoped")
     shutil.rmtree(tdir, ignore_errors=True)
     watch = _host_watch()

@@ -16,29 +16,7 @@ from bench.weights import make_weights
 
 @pytest.fixture
 def broken_step(monkeypatch):
-    from repro.train import step as program_step
-    real = program_step.make_train_step
-
-    def plant(fault):
-        def make(cfg, run, plan):
-            step = real(cfg, run, plan)
-
-            def broken(state, batch):
-                if fault == "half_batch":
-                    lab = batch["labels"]
-                    half = jnp.arange(lab.shape[-1]) >= lab.shape[-1] // 2
-                    batch = dict(batch, labels=jnp.where(half, -1, lab))
-                    return step(state, batch)
-                _, metrics = step(jax_copy(state), batch)
-                return state, metrics
-            return broken
-        monkeypatch.setattr(program_step, "make_train_step", make)
-    return plant
-
-
-def jax_copy(tree):
-    import jax
-    return jax.tree.map(jnp.copy, tree)
+    return lambda fault: harness.plant(fault, monkeypatch.setattr)
 
 
 def test_state_left_unchanged_is_caught(broken_step):

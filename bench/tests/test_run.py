@@ -6,7 +6,7 @@ import os
 
 import pytest
 
-from bench import counts, run as B
+from bench import counts, kinds, run as B
 from bench.model import load_json
 
 
@@ -43,8 +43,14 @@ def test_counts_of_the_configuration():
     per_layer = 4 * 2048 * 2048 + 3 * 2048 * 5504
     assert counts.matmul_params(c) == 8 * per_layer + 37984 * 2048
     # chunked linear attention: 2C(dk+dv) + 4 dk dv per token and head
-    assert counts.linear_attention_flops_fwd(c) == 16 * (
-        2 * 128 * 256 + 4 * 128 * 128)
+    la = 16 * (2 * 128 * 256 + 4 * 128 * 128)
+    assert kinds.kind("linear").mixing_flops(c, 8192) == la
+    # the kinds' sums give what the linear-only counts gave
+    fwd = 2 * counts.matmul_params(c) + 8 * la
+    assert counts.forward_flops_per_token(c) == fwd == 981860352
+    assert counts.train_flops_per_token(c, 8192) == 3 * fwd == 2945581056
+    assert counts.decode_flops_per_token(c) == 973471744
+    assert counts.prefill_flops(c, 300) == 248038948864
     f, b = counts.kernel_work("lasp2_chunk_fwd", bh=16, s=8192, dk=128,
                               dv=128)
     assert f == 16 * 8192 * (2 * 128 * 256 + 4 * 128 * 128)

@@ -2,9 +2,10 @@
 
 Set-up builds the train state on the device from the seed in one jitted
 call, compiles the program's train step (``repro.train.step``) on the plan
-of ``repro.sharding.rules.make_plan``, and drives that one compiled step
-through the cell's first ``CHECK_STEPS`` steps, which the reference
-follows. The window then drives the same step on fresh rows for
+of ``repro.sharding.rules.make_plan`` for the traffic's ``layout`` (dp ×
+sp over the cell's chips, see :func:`layout`), and drives that one
+compiled step through the cell's first ``CHECK_STEPS`` steps, which the
+reference follows. The window then drives the same step on fresh rows for
 ``seconds``, with one step in flight while the host makes the next
 batch. After the window the program's state is freed and the reference
 runs its own steps on the same rows.
@@ -18,6 +19,7 @@ import time
 import jax
 import jax.numpy as jnp
 from jax.profiler import TraceAnnotation as span
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from bench import correct, loadgen
 from bench.model import model_config, run_config
@@ -28,34 +30,67 @@ from bench.weights import _make, flat_from_program, make_weights, \
 CHECK_STEPS = 3
 
 
-def _feed(t, seed, step, vocab):
+def _feed(t, seed, step, vocab, sharding=None):
     b = loadgen.train_batch(t, seed, step, vocab)
-    return {k: jnp.asarray(b[k]) for k in ("tokens", "labels", "resets")}
+    if sharding is None:
+        return {k: jnp.asarray(b[k]) for k in ("tokens", "labels", "resets")}
+    return {k: jax.device_put(b[k], sharding)
+            for k in ("tokens", "labels", "resets")}
+
+
+def _one_row(t):
+    if t["rows"] != 1 or t["layout"].get("dp", 1) != 1:
+        raise NotImplementedError(
+            "the reference takes one row a step, so a layout over more "
+            "than one row (dp > 1) is not checked yet")
 
 
 def _ref_batches(t, seed, vocab):
+    _one_row(t)
     out = []
     for i in range(CHECK_STEPS):
         b = loadgen.train_batch(t, seed, i, vocab)
-        if b["tokens"].shape[:2] != (1, 1):
-            raise NotImplementedError("the reference takes one row a step")
         out.append({k: jnp.asarray(b[k][0, 0])
                     for k in ("tokens", "labels", "seg")})
     return out
 
 
+def layout(ctx):
+    """The plan of the cell's training layout, and for a layout over
+    several chips the shardings of the state and of a batch.
+
+    1 × 1 is one chip and the program's local plan. Otherwise the chips
+    form the paper's (data, sequence) mesh and the program's manual DP×SP
+    step runs over it: the state, which that plan replicates, is built in
+    place on every chip, and each batch is split over (data, sequence)."""
+    from repro.launch.mesh import DATA_AXIS, SEQ_AXIS, make_training_mesh
+    from repro.sharding.rules import make_plan
+
+    c, lay = ctx.config, ctx.traffic["layout"]
+    dp, sp = lay.get("dp", 1), lay.get("sp", 1)
+    if dp * sp != ctx.chips:
+        raise ValueError(f"layout dp {dp} x sp {sp} does not fill the "
+                         f"cell's {ctx.chips} chips")
+    _one_row(ctx.traffic)
+    if (dp, sp) == (1, 1):
+        return make_plan(None, "train"), None, None
+    mesh = make_training_mesh(dp, sp, devices=jax.devices()[:ctx.chips])
+    plan = make_plan(mesh, "train", n_heads=c["num_attention_heads"],
+                     n_kv_heads=c["num_key_value_heads"])
+    if plan.zero1_axis is not None:
+        raise ValueError("a sharded optimizer state is not built here")
+    return (plan, NamedSharding(mesh, P()),
+            NamedSharding(mesh, P(None, DATA_AXIS, SEQ_AXIS)))
+
+
 def setup(ctx):
     from repro.optim import adamw
-    from repro.sharding.rules import make_plan
     from repro.train import step as program_step
 
     c, t = ctx.config, ctx.traffic
-    lay = t["layout"]
-    if (lay.get("dp", 1), lay.get("sp", 1)) != (1, 1):
-        raise NotImplementedError("only one-chip training layouts so far")
     cfg = model_config(c)
-    run = run_config(c, lay)
-    plan = make_plan(None, "train")
+    run = run_config(c, t["layout"])
+    plan, replicated, batch_sharding = layout(ctx)
     b1 = c["optimizer"]["b1"]
 
     def build(key):
@@ -63,7 +98,10 @@ def setup(ctx):
         return {"params": params, "opt": adamw.init(params),
                 "step": jnp.zeros((), jnp.int32)}
 
-    state = jax.jit(build)(seed_key(ctx.seed))
+    if replicated is None:
+        state = jax.jit(build)(seed_key(ctx.seed))
+    else:
+        state = jax.jit(build, out_shardings=replicated)(seed_key(ctx.seed))
     step = jax.jit(program_step.make_train_step(cfg, run, plan),
                    donate_argnums=(0,))
     grad_norms = jax.jit(lambda m: ref_train.leaf_norms(
@@ -73,18 +111,20 @@ def setup(ctx):
 
     prog = {"losses": []}
     for i in range(CHECK_STEPS):
-        state, metrics = step(state, _feed(t, ctx.seed, i, c["vocab_size"]))
+        state, metrics = step(state, _feed(t, ctx.seed, i, c["vocab_size"],
+                                           batch_sharding))
         prog["losses"].append(float(metrics["loss"]))
         if i == 0:
             # Adam's first moment after one step is (1 - b1) · g, the
             # clipped gradient the optimizer got
             prog["grad"] = {n: float(x) for n, x in
                             grad_norms(state["opt"].m).items()}
-    w0 = make_weights(ctx.seed, c, jnp.float32)
+    w0 = make_weights(ctx.seed, c, jnp.float32, out_shardings=replicated)
     prog["change"] = {n: float(x) for n, x in
                       change_norms(state["params"], w0).items()}
     del w0
-    return {"state": state, "step": step, "prog": prog, "next": CHECK_STEPS}
+    return {"state": state, "step": step, "prog": prog, "next": CHECK_STEPS,
+            "batch_sharding": batch_sharding}
 
 
 def window(ctx, s):
@@ -96,7 +136,8 @@ def window(ctx, s):
     with span("window"):
         while True:
             with span("data"):
-                batch = _feed(t, ctx.seed, i, c["vocab_size"])
+                batch = _feed(t, ctx.seed, i, c["vocab_size"],
+                              s.get("batch_sharding"))
             with span("step"):
                 state, metrics = step(state, batch)
             i, n = i + 1, n + 1
@@ -125,12 +166,14 @@ def check(ctx, s, w):
     prog = s.pop("prog")
     s.clear()
     batches = _ref_batches(t, ctx.seed, c["vocab_size"])
+    t0 = time.perf_counter()
     losses, grad, change = ref_train.run(
         functools.partial(make_weights, ctx.seed, c, jnp.float32),
         batches, c)
     ref = {"losses": losses, "grad": grad, "change": change}
-    return correct.train_readings(prog, ref), {"program": prog,
-                                               "reference": ref}
+    return correct.train_readings(prog, ref), {
+        "program": prog, "reference": ref,
+        "reference_s": time.perf_counter() - t0}
 
 
 def kernel_shapes(ctx):
