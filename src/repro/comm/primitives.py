@@ -29,6 +29,8 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.obs.scopes import comm_scope
+
 _TAPE = threading.local()
 
 # Wire-dtype registry for the ``comm_dtype`` knob (docs/communication.md):
@@ -147,7 +149,8 @@ def allgather_states(x, axis: str, *, axis_size: int, gather_axis: int = 0,
     pb = _nbytes(x)
     _record(CommRecord("all-gather", pb, (axis_size - 1) * pb, steps=1,
                        group=axis_size, tag=tag))
-    return jax.lax.all_gather(x, axis, axis=gather_axis, tiled=tiled)
+    with comm_scope(tag, "all-gather"):
+        return jax.lax.all_gather(x, axis, axis=gather_axis, tiled=tiled)
 
 
 def ring_sendrecv(x, axis: str, *, axis_size: int, shift: int = 1,
@@ -164,7 +167,8 @@ def ring_sendrecv(x, axis: str, *, axis_size: int, shift: int = 1,
     pb = _nbytes(x)
     _record(CommRecord("collective-permute", pb, pb * loop_trips,
                        steps=loop_trips, group=axis_size, tag=tag))
-    return jax.lax.ppermute(x, axis, perm)
+    with comm_scope(tag, "collective-permute"):
+        return jax.lax.ppermute(x, axis, perm)
 
 
 def reduce_scatter_grads(x, axis: str, *, axis_size: int,
@@ -180,8 +184,9 @@ def reduce_scatter_grads(x, axis: str, *, axis_size: int,
     _record(CommRecord("reduce-scatter", pb,
                        (axis_size - 1) * pb // axis_size, steps=1,
                        group=axis_size, tag=tag))
-    return jax.lax.psum_scatter(x, axis, scatter_dimension=scatter_axis,
-                                tiled=tiled)
+    with comm_scope(tag, "reduce-scatter"):
+        return jax.lax.psum_scatter(x, axis, scatter_dimension=scatter_axis,
+                                    tiled=tiled)
 
 
 def psum_packed(x, axes, *, group_size: int, tag: str = ""):
@@ -196,7 +201,8 @@ def psum_packed(x, axes, *, group_size: int, tag: str = ""):
     _record(CommRecord("all-reduce", pb,
                        2 * (group_size - 1) * pb // max(group_size, 1),
                        steps=1, group=group_size, tag=tag))
-    return jax.lax.psum(x, axes)
+    with comm_scope(tag, "all-reduce"):
+        return jax.lax.psum(x, axes)
 
 
 def multi_axis_index(axis):
@@ -220,7 +226,9 @@ def _alltoall_impl(x, axis, axis_size, split_axis, concat_axis, tag):
     _record(CommRecord("all-to-all", pb,
                        (axis_size - 1) * pb // max(axis_size, 1), steps=1,
                        group=axis_size, tag=tag))
-    return jax.lax.all_to_all(x, axis, split_axis, concat_axis, tiled=True)
+    with comm_scope(tag, "all-to-all"):
+        return jax.lax.all_to_all(x, axis, split_axis, concat_axis,
+                                  tiled=True)
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5))

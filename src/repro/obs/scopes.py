@@ -37,6 +37,12 @@ SCOPES = (
     "optimizer",        # clipping or guard verdict, schedule, AdamW
     "grad_reduce",      # the manual step's packed gradient psum
 )
+# Each exchange of repro.comm.primitives opens ``comm.<tag>`` around its
+# collective (``comm_scope``): ``comm.lasp2.states``, ``comm.lasp2h.k``,
+# ``comm.lasp2h.v``, ``comm.train.grads``, ... Autodiff's mirrored
+# exchange keeps the name under ``transpose(...)``, so it reads as pass
+# ``bwd``; the all-to-all's hand-written backward is ``comm.<tag>.bwd``.
+COMM = "comm."
 
 _INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([A-Za-z0-9_\-.]+)\s*=\s")
 _OP_NAME = re.compile(r'\bop_name="([^"]*)"')
@@ -49,6 +55,16 @@ def scope(name: str):
     if name not in SCOPES:
         raise ValueError(f"unknown scope {name!r}; known: {SCOPES}")
     return jax.named_scope(name)
+
+
+def comm_scope(tag: str, op: str):
+    """``jax.named_scope("comm.<tag>")`` around one collective; an
+    untagged call is named by its op, ``comm.<op>``."""
+    return jax.named_scope(COMM + (tag or op))
+
+
+def _known(part: str) -> bool:
+    return part in SCOPES or (part.startswith(COMM) and len(part) > len(COMM))
 
 
 def parse_op_name(op_name: str) -> Tuple[Optional[str], str]:
@@ -71,7 +87,7 @@ def parse_op_name(op_name: str) -> Tuple[Optional[str], str]:
             if m is None:
                 break
             part = m.group(1)
-        if part in SCOPES:
+        if _known(part):
             found = part
     return found, kind
 

@@ -28,6 +28,7 @@ S_TRAIN = 8192                  # the linear-train-8k row
 WINDOW = 2048                   # the 1/4 hybrid's softmax window
 SLOTS = 4                       # decode batch
 ODD = 100                       # a prompt length off every block multiple
+S_HYBRID = 65536                # the hybrid-train-sp4-64k row, over 4 chips
 
 
 @pytest.fixture(scope="module")
@@ -89,13 +90,13 @@ def _flash_grad(sds):
     return jax.grad(loss, argnums=(0, 1, 2)), (q, q, q)
 
 
-def _flash_grad_traced_offset(sds):
+def _flash_grad_traced_offset(sds, s=S):
     """The LASP-2H sequence-parallel path: the rank offset is traced."""
     def loss(q_, k_, v_, off):
         o = flash_attention(q_, k_, v_, q_offset=off)
         return jnp.sum(o.astype(jnp.float32))
-    q = sds((1, BH, S // 4, D))
-    kv = sds((1, BH, S, D))
+    q = sds((1, BH, s // 4, D))
+    kv = sds((1, BH, s, D))
     return (jax.grad(loss, argnums=(0, 1, 2)),
             (q, kv, kv, sds((), jnp.int32)))
 
@@ -141,6 +142,9 @@ CASES = {
     "flash fwd": (_flash_fwd, {"flash_attention_fwd"}),
     "flash grad": (_flash_grad, _FLASH),
     "flash grad traced offset": (_flash_grad_traced_offset, _FLASH),
+    # one rank of hybrid-train-sp4-64k: q 16,384 against K/V 65,536
+    "flash grad traced offset 64k": (
+        functools.partial(_flash_grad_traced_offset, s=S_HYBRID), _FLASH),
     "three 64-token chunks grads": (_short_chunk_grads, {
         "lasp2_chunk_fwd", "lasp2_chunk_bwd_dq", "lasp2_chunk_bwd_dkv"}),
     "odd prompt length grads": (_odd_prompt_grads, _FLASH | {

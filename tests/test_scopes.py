@@ -5,14 +5,17 @@ device trace (bench/scoped.py) on the committed v5e trace."""
 import collections
 import contextlib
 import gc
+import json
 import os
 import re
+import subprocess
 import sys
 
 import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.comm import primitives
 from repro.configs import get_smoke
 from repro.configs.base import RunConfig
 from repro.models import blocks, model
@@ -84,6 +87,7 @@ def test_instruction_scopes_cover_every_layer_and_pass(compiled, flavour):
     expected = dict(EXPECTED)
     if flavour == "manual":
         expected["grad_reduce"] = {"fwd"}
+        expected["comm.train.grads"] = {"fwd"}
     assert dict(found) == expected
 
 
@@ -95,6 +99,8 @@ def test_scopes_change_metadata_only(compiled, flavour, monkeypatch):
 
     for mod in (model, blocks, train_step):
         monkeypatch.setattr(mod, "scope", null)
+    monkeypatch.setattr(primitives, "comm_scope",
+                        lambda tag, op: contextlib.nullcontext())
     bare = _compile(flavour)
     assert not any(s for s, _ in scopes.instruction_scopes(bare).values())
     counts = _opcodes(compiled[flavour])
@@ -118,9 +124,68 @@ def test_scope_refuses_unknown_names():
      "dynamic_update_slice", ("layers", "fwd")),
     ("jit(train_step)/optimizer/is_finite", ("optimizer", "fwd")),
     ("jit(train_step)/while/body/closed_call", (None, "fwd")),
+    ("jit(train_step)/shard_map/while/body/closed_call/jvp(layers)/while/"
+     "body/closed_call/checkpoint/mixer.softmax/comm.lasp2h.k/all_gather",
+     ("comm.lasp2h.k", "fwd")),
+    ("jit(train_step)/shard_map/while/body/closed_call/transpose(jvp("
+     "layers))/while/body/closed_call/checkpoint/checkpoint/mixer.softmax/"
+     "comm.lasp2h.v/reduce_scatter", ("comm.lasp2h.v", "bwd")),
+    ("jit(train_step)/shard_map/grad_reduce/comm.train.grads/psum",
+     ("comm.train.grads", "fwd")),
+    ("jit(train_step)/comm./psum", (None, "fwd")),
 ])
 def test_parse_op_name(op_name, expected):
     assert scopes.parse_op_name(op_name) == expected
+
+
+def test_hybrid_manual_step_names_its_exchanges():
+    """The LASP-2 state all-gather and LASP-2H's K/V all-gathers of a
+    1/4 hybrid at dp 1 × sp 4 carry ``comm.<tag>`` in every pass, the
+    gradient psum ``comm.train.grads``."""
+    out = subprocess.run(
+        [sys.executable, os.path.join(os.path.dirname(__file__),
+                                      "comm_scope_checks.py")],
+        capture_output=True, text=True, timeout=900)
+    assert out.returncode == 0, out.stderr[-3000:]
+    found = json.loads(out.stdout.strip().splitlines()[-1])
+    for tag in ("comm.lasp2h.k", "comm.lasp2h.v", "comm.lasp2.states"):
+        assert found[tag] == ["bwd", "fwd", "remat"], tag
+    assert found["comm.train.grads"] == ["fwd"]
+    assert found["mixer.softmax"] == ["bwd", "fwd", "remat"]
+
+
+def test_comm_scopes_leave_the_one_chip_step_unchanged(monkeypatch):
+    """``linear-train-8k``'s step on one chip, at the cell's own sizes,
+    lowers to the same text with the exchanges' scopes and without them,
+    and no location of it names one: it has no exchange."""
+    from bench import train as bench_train
+    from bench.model import load_json, model_config, run_config
+    from bench.weights import _make, program_tree
+    from repro.optim import adamw
+
+    c = load_json("bench/configs/qwen1.5-1.8b-linear.json")
+    t = load_json("bench/traffic/train-packed-8k.json")
+    cfg, run = model_config(c), run_config(c, t["layout"])
+    plan = local_plan()
+
+    def build(key):
+        params = program_tree(_make(key, c, jnp.float32), c)
+        return {"params": params, "opt": adamw.init(params),
+                "step": jnp.zeros((), jnp.int32)}
+
+    state = jax.eval_shape(build, jax.random.PRNGKey(0))
+    batch = jax.eval_shape(lambda: bench_train._feed(t, 1, 0,
+                                                     c["vocab_size"]))
+
+    def lowered():
+        return jax.jit(train_step.make_train_step(cfg, run, plan)).lower(
+            state, batch)
+
+    with_scopes = lowered()
+    assert scopes.COMM not in with_scopes.as_text(debug_info=True)
+    monkeypatch.setattr(primitives, "comm_scope",
+                        lambda tag, op: contextlib.nullcontext())
+    assert lowered().as_text() == with_scopes.as_text()
 
 
 def test_host_watch_counts_gc_and_compiles():
@@ -230,3 +295,121 @@ def test_scope_readers_read_nothing_from_a_program_without_scopes():
         "has_scopes": False, "step_gap_ms": 0.05, "scope_ms": {}}}
     assert _reader("mlp_ms.train")(record) is None
     assert _reader("step_gap_ms.train")(record) == pytest.approx(0.05)
+
+
+# ---------------------------------------------------------------------------
+# Per-device readings over several chips (bench/ranks.py) and the readers
+# of the hybrid cell
+# ---------------------------------------------------------------------------
+
+HLO = """\
+  %all-gather-start.1 = (bf16[4,8]{1,0}, bf16[16,8]{1,0}) all-gather-start(bf16[4,8]{1,0} %x), channel_id=1, metadata={op_name="jit(s)/comm.lasp2h.k/all_gather"}
+  %all-gather-done.1 = bf16[16,8]{1,0} all-gather-done((bf16[4,8]{1,0}, bf16[16,8]{1,0}) %all-gather-start.1)
+  %fusion.3 = f32[8]{0} fusion(f32[8]{0} %a), kind=kLoop, calls=%fused_computation.3
+  %all-reduce-scatter-fusion.1 = f32[2]{0} fusion(f32[8]{0} %b), kind=kOutput, calls=%all-reduce-scatter.2
+  %psum.7 = f32[255298]{0} all-reduce(f32[255298]{0} %concatenate.127), channel_id=2
+  %dot.4 = f32[8,8]{1,0} dot(f32[8,8]{1,0} %p, f32[8,8]{1,0} %q)
+"""
+
+
+def test_collective_instructions_by_opcode_name_and_callee():
+    from bench import ranks
+    assert ranks.collective_instructions(HLO) == {
+        "all-gather-start.1", "all-gather-done.1",
+        "all-reduce-scatter-fusion.1", "psum.7"}
+
+
+def test_exposed_collective_time_is_what_no_other_op_covers():
+    from bench import ranks
+    dev = {"modules": [("jit_step", 0, 100, 1), ("jit_step", 200, 300, 2)],
+           "ops": [("fusion.1", 0, 40), ("all-gather-done.1", 30, 60),
+                   ("fusion.2", 60, 100), ("while.1", 0, 100),
+                   ("psum.7", 200, 250), ("fusion.3", 220, 300),
+                   ("psum.7", 350, 400)]}
+    scopes_ = {"all-gather-done.1": ("comm.lasp2h.k", "bwd"),
+               "psum.7": ("comm.train.grads", "fwd")}
+    r = ranks.exposed(dev, "jit_step", {"all-gather-done.1", "psum.7"},
+                      scopes_)
+    # 20 ns of each collective are bare; the last psum is outside a step
+    assert r["exposed_ns"] == pytest.approx(20.0)
+    assert r["collective_ns"] == pytest.approx(40.0)
+    assert r["by_label"] == {"comm.lasp2h.k.bwd": pytest.approx(10.0),
+                             "comm.train.grads": pytest.approx(10.0)}
+    assert ranks.exposed(dev, "jit_other", set(), {}) is None
+
+
+def _ranks_record(kind="train"):
+    # every device's step takes 1000 ms; the one with the longest softmax
+    # sets it, and the others wait for it in a collective
+    def dev(softmax_ms, exposed_ns):
+        return {"scopes": {"busy_ms": 1000.0, "scope_ms": {"mixer.softmax": {
+                    "fwd": softmax_ms / 4, "remat": softmax_ms / 4,
+                    "bwd": softmax_ms / 2}}},
+                "exposed": {"exposed_ns": exposed_ns, "collective_ns": 0.0,
+                            "by_label": {"comm.lasp2h.k": exposed_ns}}}
+    return {"ctx": _Ctx(kind), "ranks": {"has_scopes": True, "devices": {
+        "/device:TPU:0": dev(400.0, 303e6), "/device:TPU:1": dev(700.0, 3e6),
+        "/device:TPU:2": dev(500.0, 201e6)}}}
+
+
+def test_worst_device_readers():
+    """The softmax reads its longest device; the exposed collectives read
+    the device the others wait for, not the longest wait."""
+    from bench import ranks
+    assert ranks.critical_device(_ranks_record()["ranks"]) == \
+        "/device:TPU:1"
+    assert _reader("softmax_mixer_ms.train")(_ranks_record()) == \
+        pytest.approx(700.0)
+    assert _reader("exposed_collective_ms.train")(_ranks_record()) == \
+        pytest.approx(3.0)
+    none = {"ctx": _Ctx("serve")}
+    for name in ("softmax_mixer_ms.train", "exposed_collective_ms.train"):
+        assert _reader(name)(dict(none)) is None
+
+
+def _hybrid_ctx():
+    from types import SimpleNamespace
+
+    from bench.model import load_json
+    return SimpleNamespace(
+        config=load_json("bench/configs/qwen1.5-1.8b-hybrid4.json"),
+        traffic=load_json("bench/traffic/train-sp4-64k.json"), chips=4,
+        peaks={"flops": 197e12, "hbm_bw": 819e9})
+
+
+def test_mfu_seq_counts_the_softmax_layer_at_the_row_length():
+    from bench import counts
+    ctx = _hybrid_ctx()
+    window = {"tokens": 10 * 65536, "window_s": 4.0}
+    got = _reader("mfu_seq.train")({"ctx": ctx, "window": window})
+    want = 100.0 * counts.train_flops_per_token(ctx.config, 65536) * \
+        10 * 65536 / (4.0 * 4 * 197e12)
+    assert got == pytest.approx(want)
+    # a configuration whose count does not depend on the row reads as
+    # mfu.train does
+    from bench.model import load_json
+    ctx.config = load_json("bench/configs/qwen1.5-1.8b-linear.json")
+    assert _reader("mfu_seq.train")({"ctx": ctx, "window": window}) == \
+        pytest.approx(_reader("mfu.train")({"ctx": ctx, "window": window}))
+
+
+def test_flash_roofline_sums_the_ranks_needed_work():
+    from bench import counts, kinds
+    ctx = _hybrid_ctx()
+    softmax = kinds.kind("softmax")
+    trace = {"op_calls": {"flash_attention_fwd": 2.0,
+                          "flash_attention_bwd_dq": 1.0},
+             "op_s": {"flash_attention_fwd": 0.8,
+                      "flash_attention_bwd_dq": 0.5}}
+    least = 0.0
+    for rank in range(4):
+        w = softmax.rank_work(ctx.config, ctx.traffic, rank)
+        least += 2 * counts.least_time(*w["flash_attention_fwd"],
+                                       ctx.peaks)[0]
+        least += counts.least_time(*w["flash_attention_bwd_dq"],
+                                   ctx.peaks)[0]
+    got = _reader("flash_attention_roofline")({"ctx": ctx, "trace": trace})
+    assert got == pytest.approx(100.0 * least / (4 * (0.8 + 0.5)))
+    assert 0 < got < 100
+    assert _reader("flash_attention_roofline")(
+        {"ctx": ctx, "trace": {"op_calls": {}, "op_s": {}}}) is None
