@@ -5,13 +5,41 @@ compute after the K/V AllGather — paper Alg. 7 line 7) and by prefill.
 
 Forward grid = ``(B, Hq, nq, kv_band)``; the kv axis is the innermost
 sequential axis; ``(m, l, acc)`` live in VMEM scratch and are reset when
-the band index is 0. The per-row softmax statistics ``lse = m + log l``
-are written out as a second output, a ``(B, Hq, 1, Sq)`` array in
-``(1, block_q)`` rows (``repro.kernels.layout``) — the backward
-residuals of the standard flash scheme (Dao 2023; Lightning Attention-2
-keeps the same tile loop resident on-chip for its backward, the pattern
-followed here). A traced ``q_offset`` reaches the kernels as a scalar
-in SMEM.
+the band index is 0. The running max ``m`` and sum ``l`` are lane-dense
+``(block_q, 128)`` tiles, each row's value repeated across the lanes, so
+they meet a ``(block_q, block_k)`` score tile without a lane broadcast.
+The per-row softmax statistics ``lse = m + log l`` are written out as a
+second output, a ``(B, Hq, 1, Sq)`` array in ``(1, block_q)`` rows
+(``repro.kernels.layout``) — the backward residuals of the standard flash
+scheme (Dao 2023; Lightning Attention-2 keeps the same tile loop resident
+on-chip for its backward, the pattern followed here). A traced
+``q_offset`` reaches the kernels as a scalar in SMEM.
+
+Tiles: :func:`pick_tiles` takes ``(block_q, block_k)`` from what a call
+sees — the query and key lengths (a multiple of 128 after the padding in
+``repro.kernels.ops``), the sliding window and the input dtype: the
+largest 128-multiple that divides each length, up to ``MAX_TILE_Q`` /
+``MAX_TILE_K`` (``MAX_TILE_F32`` for fp32 inputs), and with a sliding
+window at most a quarter of it, so that band trimming still cuts whole
+tiles. A grid step has a fixed cost (about 0.2 µs on a v5e) and re-reads
+the resident operand's partner from HBM, so a tile of several hundred
+rows pays both once for many 128 × 128 block pairs; 1024 × 1024 tiles
+compile for a v5e within the default scoped VMEM. Explicit ``block_q`` /
+``block_k`` override the pick.
+
+The block step keeps the fp32 softmax math and feeds the MXU as cheaply
+as that allows. Products of two stored bf16 tiles (``q·kᵀ``; ``dO·vᵀ``
+in the backward) go to the MXU as bf16 with fp32 accumulation: a product
+of two bf16 values is exact in fp32. Products of an in-kernel fp32 tile
+with a stored bf16 one (``p·v``, ``ds·k``, ``pᵀ·dO``, ``dsᵀ·q``) split the
+fp32 side into two bf16 terms, ``hi = bf16(x)`` and ``lo = bf16(x −
+hi)``: 16 significant bits, the result a three-pass fp32 product gives
+when one side is exactly bf16. ``exp``, the running statistics and the
+accumulators stay fp32, and fp32 inputs keep fp32 products (at the
+compiler's default precision, as before). The element
+mask is built only on tiles that cross the causal diagonal, the window's
+edge or ``kv_len`` — a scalar test per tile picks the masked or the
+unmasked step.
 
 Causal grid trimming: the kv grid axis is a *band*, not the full kv
 extent — for each q block the index maps offset by that block's first
@@ -29,13 +57,18 @@ The backward follows FlashAttention-2's two-pass scheme:
 * ``dq`` — same grid/band as the forward; ``p = exp(s - lse)`` is
   recomputed blockwise from the saved stats, ``ds = p (dO·V^T − delta)``
   with ``delta_i = dO_i·o_i`` precomputed rowwise, and ``dq += ds K``
-  accumulates in VMEM scratch across the kv band.
+  accumulates in VMEM scratch across the kv band. The q tile is resident
+  across the band, so ``lse`` and ``delta`` are turned from rows into
+  lane-dense columns once, at the first band step, into scratch.
 * ``dk/dv`` — kv-major grid ``(B, Hkv, nkv, rep, q_band)`` iterating the
   *transposed* band (the reverse orientation of the forward loop): each
   kv tile stays resident while the q-head group (``rep`` = GQA ratio)
   and its q band stream by, so dk/dv are accumulated across the whole
   q-head group in fp32 scratch and written once — KV tiles are fetched
-  once per group instead of once per q head.
+  once per group instead of once per q head. The step works on the
+  transposed scores ``sᵀ = k·qᵀ`` ``(block_k, block_q)``: ``lse`` and
+  ``delta`` then broadcast down the sublanes as the ``(1, block_q)`` rows
+  they arrive in, and ``dv += pᵀ·dO``, ``dk += dsᵀ·q`` are plain products.
 
 GQA is expressed in the K/V index maps (``hq // rep``), so KV tiles are
 fetched once per q-head group without materializing repeated heads.
@@ -61,8 +94,17 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.layout import col_to_row, row_to_col
 
-DEFAULT_BLOCK_Q = 128
-DEFAULT_BLOCK_K = 128
+LANES = 128
+# Largest tiles a call picks, for bf16 and for fp32 inputs. Chosen on a
+# v5e by scripts/flash_tile_sweep.py at one LASP-2H rank's shape (PERF.md
+# §6): 1024 × 1024 beat 512 × 512 by 9 % a live block pair; fp32 was
+# swept to 512.
+MAX_TILE_Q = 1024
+MAX_TILE_K = 1024
+MAX_TILE_F32 = 512
+
+_NT = (((1,), (1,)), ((), ()))     # x @ y^T
+_NN = (((1,), (0,)), ((), ()))     # x @ y
 
 
 def mask_value(dtype) -> float:
@@ -70,6 +112,41 @@ def mask_value(dtype) -> float:
     ``finfo`` so reduced-precision score dtypes (bf16/fp16) cannot
     overflow to ``-inf``/NaN the way a ``-1e30`` literal does in fp16."""
     return float(jnp.finfo(jnp.dtype(dtype)).min) * 0.5
+
+
+# ---------------------------------------------------------------------------
+# Tiles.
+# ---------------------------------------------------------------------------
+
+def _tile(n: int, cap: int) -> int:
+    """Largest multiple of 128 that divides ``n`` and is at most ``cap``;
+    ``n`` itself when it is at most 128 (one tile spans the array), and
+    128 when no multiple of 128 divides it (the caller pads)."""
+    if n <= LANES:
+        return n
+    tile = LANES
+    for t in range(2 * LANES, min(n, cap) + 1, LANES):
+        if n % t == 0:
+            tile = t
+    return tile
+
+
+def pick_tiles(sq: int, sk: int, *, sliding_window=None,
+               dtype=jnp.bfloat16):
+    """``(block_q, block_k)`` for ``sq`` queries against ``sk`` keys, each a
+    multiple of 128 or at most 128. bf16 inputs take tiles up to
+    ``MAX_TILE_Q`` × ``MAX_TILE_K``, other dtypes up to ``MAX_TILE_F32``.
+    A sliding window caps both tiles at a quarter of the window (128 at
+    least): a q tile's kv band then spans about the window and two tiles
+    more, so trimming keeps about four fifths of the computed pairs live."""
+    if jnp.dtype(dtype) == jnp.bfloat16:
+        cap_q, cap_k = MAX_TILE_Q, MAX_TILE_K
+    else:
+        cap_q = cap_k = MAX_TILE_F32
+    if sliding_window is not None:
+        w = max(LANES, sliding_window // 4 // LANES * LANES)
+        cap_q, cap_k = min(cap_q, w), min(cap_k, w)
+    return _tile(sq, cap_q), _tile(sk, cap_k)
 
 
 # ---------------------------------------------------------------------------
@@ -152,18 +229,20 @@ def _q_band(*, nq: int, nkv: int, block_q: int, block_k: int,
 
 
 def _block_mask(qoff, q_start, k_start, block_q, block_k, *, causal,
-                sliding_window, kv_len):
-    """(block_q, block_k) validity mask in *global* coordinates.
+                sliding_window, kv_len, transposed=False):
+    """(block_q, block_k) validity mask in *global* coordinates —
+    ``(block_k, block_q)``, keys down the sublanes, when ``transposed``.
 
     Query row i of a block sits at global position ``qoff + q_start + i``
     (``qoff = sk - sq`` for prefill-with-cache / ring-decode shapes, the
     SP rank offset under LASP-2H; key positions are global already).
     ``kv_len`` masks right-padded keys (awkward-length dispatch).
     """
-    qpos = qoff + q_start + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-    kpos = k_start + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
+    shape = (block_k, block_q) if transposed else (block_q, block_k)
+    q_axis, k_axis = (1, 0) if transposed else (0, 1)
+    qpos = qoff + q_start + jax.lax.broadcasted_iota(jnp.int32, shape,
+                                                     q_axis)
+    kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, shape, k_axis)
     mask = kpos < kv_len
     if causal:
         mask &= qpos >= kpos
@@ -184,6 +263,84 @@ def _block_needed(qoff, q_start, k_start, block_q, block_k, *, causal,
     return needed
 
 
+def _block_full(qoff, q_start, k_start, block_q, block_k, *, causal,
+                sliding_window, kv_len):
+    """Every pair of the block valid: the block needs no element mask."""
+    full = jnp.asarray(k_start + block_k <= kv_len)
+    if causal:
+        full &= k_start + block_k - 1 <= qoff + q_start
+    if sliding_window is not None:
+        full &= (qoff + q_start + block_q - 1 - k_start) < sliding_window
+    return full
+
+
+def _steps(step, needed, full):
+    """Run ``step(masked)`` on a needed block: unmasked when it is full."""
+    pl.when(jnp.logical_and(needed, full))(lambda: step(False))
+    pl.when(jnp.logical_and(needed, jnp.logical_not(full)))(
+        lambda: step(True))
+
+
+# ---------------------------------------------------------------------------
+# Block-step helpers.
+# ---------------------------------------------------------------------------
+
+def _load(ref):
+    """A stored tile as the block step takes it: bf16 as it is, any other
+    dtype as fp32."""
+    x = ref[0, 0]
+    return x if x.dtype == jnp.bfloat16 else x.astype(jnp.float32)
+
+
+def _dot(x, y, dims):
+    """``x·y`` over ``dims`` with fp32 accumulation. Two bf16 tiles go to
+    the MXU as they are. An fp32 ``x`` against a bf16 ``y`` goes as the two
+    bf16 terms ``hi = bf16(x)`` and ``lo = bf16(x − hi)``; two fp32 tiles as
+    fp32."""
+    if x.dtype == jnp.float32 and y.dtype == jnp.bfloat16:
+        hi = x.astype(jnp.bfloat16)
+        lo = (x - hi.astype(jnp.float32)).astype(jnp.bfloat16)
+        return (jax.lax.dot_general(hi, y, dims,
+                                    preferred_element_type=jnp.float32)
+                + jax.lax.dot_general(lo, y, dims,
+                                      preferred_element_type=jnp.float32))
+    return jax.lax.dot_general(x, y, dims,
+                               preferred_element_type=jnp.float32)
+
+
+def _lanes(x, n):
+    """A lane-dense ``(r, 128)`` statistic (each row one value) as ``(r, n)``."""
+    w = x.shape[1]
+    if n == w:
+        return x
+    if n < w:
+        return x[:, :n]
+    if n % w == 0:
+        return jnp.tile(x, (1, n // w))
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
+def _chunks(n):
+    """Static slices of 128 rows, or one of all ``n`` rows when ``n`` is no
+    multiple of 128: each row/column move then masks a small square."""
+    c = LANES if n % LANES == 0 else n
+    return [slice(j, j + c) for j in range(0, n, c)]
+
+
+def _store_row(ref, col):
+    """Lane-dense ``(n, 128)`` column into the ``(1, n)`` row of ``ref``."""
+    for sl in _chunks(col.shape[0]):
+        ref[0, 0, :, sl] = col_to_row(col[sl, :1])
+
+
+def _load_col(ref, scr):
+    """The ``(1, n)`` row of ``ref`` into the lane-dense ``(n, 128)``
+    scratch ``scr``."""
+    for sl in _chunks(scr.shape[0]):
+        col = row_to_col(ref[0, 0, :, sl])
+        scr[sl, :] = jnp.broadcast_to(col, (col.shape[0], scr.shape[1]))
+
+
 # ---------------------------------------------------------------------------
 # Forward kernel.
 # ---------------------------------------------------------------------------
@@ -194,6 +351,7 @@ def _fwd_kernel(qoff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     iq = pl.program_id(2)
     ikb = pl.program_id(3)
     neg = mask_value(jnp.float32)
+    dh = acc_scr.shape[1]
 
     @pl.when(ikb == 0)
     def _init():
@@ -206,44 +364,39 @@ def _fwd_kernel(qoff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     ik = jnp.clip(lo + ikb, 0, jnp.maximum(kv_hi(iq), 0))
     q_start = iq * block_q
     k_start = ik * block_k
+    geo = dict(causal=causal, sliding_window=sliding_window, kv_len=kv_len)
     # in_extent kills the clamped band tail (repeats of the diagonal
     # block, already accumulated); the positional predicate kills
     # dynamically-dead blocks when q_offset is traced (band untrimmed).
     needed = jnp.logical_and(
         lo + ikb <= kv_hi(iq),
-        _block_needed(qoff, q_start, k_start, block_q, block_k,
-                      causal=causal, sliding_window=sliding_window,
-                      kv_len=kv_len))
+        _block_needed(qoff, q_start, k_start, block_q, block_k, **geo))
 
-    @pl.when(needed)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)       # (bq, dh)
-        k = k_ref[0, 0].astype(jnp.float32)       # (bk, dh)
-        v = v_ref[0, 0].astype(jnp.float32)       # (bk, dh)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale       # (bq, bk)
-        mask = _block_mask(qoff, q_start, k_start, block_q, block_k,
-                           causal=causal, sliding_window=sliding_window,
-                           kv_len=kv_len)
-        s = jnp.where(mask, s, neg)
-
-        m_prev = m_scr[...]                            # (bq, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        p = jnp.where(mask, p, 0.0)
+    def step(masked):
+        s = _dot(_load(q_ref), _load(k_ref), _NT) * scale     # (bq, bk)
+        if masked:
+            mask = _block_mask(qoff, q_start, k_start, block_q, block_k,
+                               **geo)
+            s = jnp.where(mask, s, neg)
+        m_prev = m_scr[...]                                 # (bq, 128)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - _lanes(m_new, block_k))
+        if masked:      # rows with no valid key yet have m_new == neg
+            p = jnp.where(mask, p, 0.0)
         corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        l_scr[...] = corr * l_scr[...] + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * _lanes(corr, dh) + _dot(
+            p, _load(v_ref), _NN)
         m_scr[...] = m_new
+
+    _steps(step, needed,
+           _block_full(qoff, q_start, k_start, block_q, block_k, **geo))
 
     @pl.when(ikb == kv_band - 1)
     def _finalize():
-        l = jnp.maximum(l_scr[...], 1e-30)             # (bq, 1)
-        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
-        lse_ref[0, 0] = col_to_row(m_scr[...] + jnp.log(l))
+        l = jnp.maximum(l_scr[...], 1e-30)             # (bq, 128)
+        o_ref[0, 0] = (acc_scr[...] / _lanes(l, dh)).astype(o_ref.dtype)
+        _store_row(lse_ref, m_scr[...] + jnp.log(l))
 
 
 def _fwd_call(q, k, v, qoff_arr, *, causal, sliding_window, scale,
@@ -288,8 +441,8 @@ def _fwd_call(q, k, v, qoff_arr, *, causal, sliding_window, scale,
             jax.ShapeDtypeStruct((b, hq, 1, sq), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, LANES), jnp.float32),
+            pltpu.VMEM((block_q, LANES), jnp.float32),
             pltpu.VMEM((block_q, dh), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
@@ -306,49 +459,41 @@ def _fwd_call(q, k, v, qoff_arr, *, causal, sliding_window, scale,
 # ---------------------------------------------------------------------------
 
 def _bwd_dq_kernel(qoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                   delta_ref, dq_ref, dq_scr, *, scale, causal,
-                   sliding_window, q_offset, kv_len, kv_lo, kv_hi, kv_band,
-                   block_q, block_k):
+                   delta_ref, dq_ref, dq_scr, lse_scr, delta_scr, *, scale,
+                   causal, sliding_window, q_offset, kv_len, kv_lo, kv_hi,
+                   kv_band, block_q, block_k):
     iq = pl.program_id(2)
     ikb = pl.program_id(3)
 
     @pl.when(ikb == 0)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
+        _load_col(lse_ref, lse_scr)
+        _load_col(delta_ref, delta_scr)
 
     qoff = q_offset if q_offset is not None else qoff_ref[0]
     lo = kv_lo(iq)
     ik = jnp.clip(lo + ikb, 0, jnp.maximum(kv_hi(iq), 0))
     q_start = iq * block_q
     k_start = ik * block_k
+    geo = dict(causal=causal, sliding_window=sliding_window, kv_len=kv_len)
     needed = jnp.logical_and(
         lo + ikb <= kv_hi(iq),
-        _block_needed(qoff, q_start, k_start, block_q, block_k,
-                      causal=causal, sliding_window=sliding_window,
-                      kv_len=kv_len))
+        _block_needed(qoff, q_start, k_start, block_q, block_k, **geo))
 
-    @pl.when(needed)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)        # (bq, dh)
-        k = k_ref[0, 0].astype(jnp.float32)        # (bk, dh)
-        v = v_ref[0, 0].astype(jnp.float32)        # (bk, dh)
-        do = do_ref[0, 0].astype(jnp.float32)      # (bq, dh)
-        lse = row_to_col(lse_ref[0, 0])            # (bq, 1)
-        delta = row_to_col(delta_ref[0, 0])        # (bq, 1)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        mask = _block_mask(qoff, q_start, k_start, block_q, block_k,
-                           causal=causal, sliding_window=sliding_window,
-                           kv_len=kv_len)
-        p = jnp.where(mask, jnp.exp(s - lse), 0.0)            # (bq, bk)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)                # (bq, bk)
-        ds = p * (dp - delta)
-        dq_scr[...] = dq_scr[...] + jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    def step(masked):
+        k = _load(k_ref)
+        s = _dot(_load(q_ref), k, _NT) * scale                 # (bq, bk)
+        p = jnp.exp(s - _lanes(lse_scr[...], block_k))
+        if masked:
+            p = jnp.where(_block_mask(qoff, q_start, k_start, block_q,
+                                      block_k, **geo), p, 0.0)
+        dp = _dot(_load(do_ref), _load(v_ref), _NT)             # (bq, bk)
+        ds = p * (dp - _lanes(delta_scr[...], block_k))
+        dq_scr[...] = dq_scr[...] + _dot(ds, k, _NN)
+
+    _steps(step, needed,
+           _block_full(qoff, q_start, k_start, block_q, block_k, **geo))
 
     @pl.when(ikb == kv_band - 1)
     def _finalize():
@@ -373,37 +518,28 @@ def _bwd_dkv_kernel(qoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     iq = jnp.clip(lo + iqb, 0, jnp.maximum(q_hi(ik), 0))
     q_start = iq * block_q
     k_start = ik * block_k
+    geo = dict(causal=causal, sliding_window=sliding_window, kv_len=kv_len)
     needed = jnp.logical_and(
         lo + iqb <= q_hi(ik),
-        _block_needed(qoff, q_start, k_start, block_q, block_k,
-                      causal=causal, sliding_window=sliding_window,
-                      kv_len=kv_len))
+        _block_needed(qoff, q_start, k_start, block_q, block_k, **geo))
 
-    @pl.when(needed)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)        # (bq, dh)
-        k = k_ref[0, 0].astype(jnp.float32)        # (bk, dh)
-        v = v_ref[0, 0].astype(jnp.float32)        # (bk, dh)
-        do = do_ref[0, 0].astype(jnp.float32)      # (bq, dh)
-        lse = row_to_col(lse_ref[0, 0])            # (bq, 1)
-        delta = row_to_col(delta_ref[0, 0])        # (bq, 1)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        mask = _block_mask(qoff, q_start, k_start, block_q, block_k,
-                           causal=causal, sliding_window=sliding_window,
-                           kv_len=kv_len)
-        p = jnp.where(mask, jnp.exp(s - lse), 0.0)            # (bq, bk)
-        dv_scr[...] = dv_scr[...] + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)                # (bk, dh)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)                # (bq, bk)
-        ds = p * (dp - delta)
-        dk_scr[...] = dk_scr[...] + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)                # (bk, dh)
+    def step(masked):
+        # Transposed orientation: keys down the sublanes, queries along
+        # the lanes, so the (1, bq) lse/delta rows broadcast as they are.
+        q, do = _load(q_ref), _load(do_ref)
+        st = _dot(_load(k_ref), q, _NT) * scale                 # (bk, bq)
+        pt = jnp.exp(st - lse_ref[0, 0])
+        if masked:
+            pt = jnp.where(_block_mask(qoff, q_start, k_start, block_q,
+                                       block_k, transposed=True, **geo),
+                           pt, 0.0)
+        dv_scr[...] = dv_scr[...] + _dot(pt, do, _NN)          # (bk, dh)
+        dpt = _dot(_load(v_ref), do, _NT)                       # (bk, bq)
+        dst = pt * (dpt - delta_ref[0, 0])
+        dk_scr[...] = dk_scr[...] + _dot(dst, q, _NN)          # (bk, dh)
+
+    _steps(step, needed,
+           _block_full(qoff, q_start, k_start, block_q, block_k, **geo))
 
     @pl.when(jnp.logical_and(ig == rep - 1, iqb == q_band - 1))
     def _finalize():
@@ -450,7 +586,9 @@ def _bwd_call(q, k, v, qoff_arr, o, lse, do, *, causal, sliding_window,
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, dh), q_im),
         out_shape=jax.ShapeDtypeStruct((b, hq, sq, dh), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, dh), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_q, dh), jnp.float32),
+                        pltpu.VMEM((block_q, LANES), jnp.float32),
+                        pltpu.VMEM((block_q, LANES), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
@@ -549,8 +687,8 @@ _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 def flash_attention(q, k, v, *, causal: bool = True, sliding_window=None,
                     scale=None, q_offset=None, kv_len: Optional[int] = None,
-                    block_q: int = DEFAULT_BLOCK_Q,
-                    block_k: int = DEFAULT_BLOCK_K, interpret: bool = False):
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None, interpret: bool = False):
     """GQA flash attention (differentiable). q: (B,Hq,S,dh), k/v: (B,Hkv,Sk,dh).
 
     ``q_offset``: global position of query row 0 (keys are global
@@ -561,6 +699,9 @@ def flash_attention(q, k, v, *, causal: bool = True, sliding_window=None,
 
     ``kv_len``: number of valid (unpadded) key positions, for callers
     that right-pad ``sk`` to a block multiple. Defaults to ``sk``.
+
+    ``block_q`` / ``block_k``: tile sizes; ``None`` takes
+    :func:`pick_tiles`'s for these lengths, window and dtype.
 
     Gradients flow to q/k/v through the two-pass Pallas backward
     (``jax.custom_vjp``).
@@ -573,8 +714,10 @@ def flash_attention(q, k, v, *, causal: bool = True, sliding_window=None,
         q_offset = sk - sq
     if kv_len is None:
         kv_len = sk
-    block_q = min(block_q, sq)
-    block_k = min(block_k, sk)
+    auto_q, auto_k = pick_tiles(sq, sk, sliding_window=sliding_window,
+                                dtype=q.dtype)
+    block_q = auto_q if block_q is None else min(block_q, sq)
+    block_k = auto_k if block_k is None else min(block_k, sk)
     if sq % block_q or sk % block_k:
         raise ValueError(f"sq={sq}, sk={sk} not divisible by blocks "
                          f"({block_q}, {block_k})")

@@ -117,10 +117,16 @@ def linear_decode_op(q, k, v, log_a, state, log_decay, *,
     return recurrent_step(q, k, v, log_a, state=state, log_decay=log_decay)
 
 
+def _round_up(n: int, m: int) -> int:
+    """``n`` itself when it is at most ``m``, else the next multiple of
+    ``m``."""
+    return n if n <= m else -(-n // m) * m
+
+
 def flash_attention_op(q, k, v, *, causal: bool = True, sliding_window=None,
                        scale=None, backend: Optional[str] = None,
-                       block_q: int = 128, block_k: int = 128,
-                       q_offset=None):
+                       block_q: Optional[int] = None,
+                       block_k: Optional[int] = None, q_offset=None):
     """GQA softmax attention (differentiable). q: (B,Hq,S,dh); k/v:
     (B,Hkv,Sk,dh).
 
@@ -135,6 +141,13 @@ def flash_attention_op(q, k, v, *, causal: bool = True, sliding_window=None,
     ``kv_len`` and padded query rows are sliced off (their cotangents are
     zeroed by the pad/slice transpose) — so the Pallas path runs on odd
     prompt lengths instead of silently dropping to XLA.
+
+    Block defaults: with ``block_q`` / ``block_k`` left ``None``, each
+    length over 128 is padded to the next multiple of 128 (by less than
+    128) and the kernel's ``pick_tiles`` takes the tiles from the padded
+    lengths, the window and the input dtype; a length of at most 128 is
+    one whole tile. Explicit blocks are honoured, each capped at its
+    length, and the lengths padded to them.
     """
     backend = resolve_backend(backend)
     if _compat.is_tracer(sliding_window):
@@ -143,7 +156,11 @@ def flash_attention_op(q, k, v, *, causal: bool = True, sliding_window=None,
     if q_offset is None:
         q_offset = sk - sq
     if backend in ("pallas", "interpret"):
-        bq, bk = min(block_q, sq), min(block_k, sk)
+        auto_q, auto_k = _flash.pick_tiles(
+            _round_up(sq, 128), _round_up(sk, 128),
+            sliding_window=sliding_window, dtype=q.dtype)
+        bq = auto_q if block_q is None else min(block_q, sq)
+        bk = auto_k if block_k is None else min(block_k, sk)
         pad_q, pad_k = -sq % bq, -sk % bk
         if pad_q:
             q = jnp.pad(q, ((0, 0), (0, 0), (0, pad_q), (0, 0)))

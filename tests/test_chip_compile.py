@@ -90,15 +90,25 @@ def _flash_grad(sds):
     return jax.grad(loss, argnums=(0, 1, 2)), (q, q, q)
 
 
-def _flash_grad_traced_offset(sds, s=S):
+def _flash_grad_traced_offset(sds, s=S, dtype=jnp.bfloat16):
     """The LASP-2H sequence-parallel path: the rank offset is traced."""
     def loss(q_, k_, v_, off):
         o = flash_attention(q_, k_, v_, q_offset=off)
         return jnp.sum(o.astype(jnp.float32))
-    q = sds((1, BH, s // 4, D))
-    kv = sds((1, BH, s, D))
+    q = sds((1, BH, s // 4, D), dtype)
+    kv = sds((1, BH, s, D), dtype)
     return (jax.grad(loss, argnums=(0, 1, 2)),
             (q, kv, kv, sds((), jnp.int32)))
+
+
+def _flash_grad_prefill(sds):
+    """A prefill against its cache: static offset sk - sq, the window."""
+    def loss(q_, k_, v_):
+        o = flash_attention(q_, k_, v_, sliding_window=WINDOW)
+        return jnp.sum(o.astype(jnp.float32))
+    kv = sds((1, BH, S, D))
+    return jax.grad(loss, argnums=(0, 1, 2)), (sds((1, BH, S // 4, D)),
+                                               kv, kv)
 
 
 def _odd_prompt_grads(sds):
@@ -145,6 +155,11 @@ CASES = {
     # one rank of hybrid-train-sp4-64k: q 16,384 against K/V 65,536
     "flash grad traced offset 64k": (
         functools.partial(_flash_grad_traced_offset, s=S_HYBRID), _FLASH),
+    # fp32 inputs pick smaller tiles and keep fp32 products
+    "flash grad traced offset 64k fp32": (
+        functools.partial(_flash_grad_traced_offset, s=S_HYBRID,
+                          dtype=jnp.float32), _FLASH),
+    "flash grad prefill": (_flash_grad_prefill, _FLASH),
     "three 64-token chunks grads": (_short_chunk_grads, {
         "lasp2_chunk_fwd", "lasp2_chunk_bwd_dq", "lasp2_chunk_bwd_dkv"}),
     "odd prompt length grads": (_odd_prompt_grads, _FLASH | {

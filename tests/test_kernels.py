@@ -45,15 +45,17 @@ def test_lasp2_chunk_kernel_sweep(rng, s, dk, dv, dtype, decay):
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("causal,window", [(True, None), (False, None),
                                            (True, 64), (False, 64)])
+@pytest.mark.parametrize("block", [64, None])
 def test_flash_kernel_sweep(rng, sq, sk, hq, hkv, dh, dtype, causal,
-                            window):
+                            window, block):
+    """Forward at 64-row blocks and at the tiles ``pick_tiles`` takes."""
     b = 2
     ks = jax.random.split(rng, 3)
     q = (jax.random.normal(ks[0], (b, hq, sq, dh)) * 0.4).astype(dtype)
     k = (jax.random.normal(ks[1], (b, hkv, sk, dh)) * 0.4).astype(dtype)
     v = (jax.random.normal(ks[2], (b, hkv, sk, dh)) * 0.5).astype(dtype)
     o = flash_attention(q, k, v, causal=causal, sliding_window=window,
-                        block_q=64, block_k=64, interpret=True)
+                        block_q=block, block_k=block, interpret=True)
     oref = flash_attention_ref(q, k, v, causal=causal,
                                sliding_window=window)
     t = TOL[dtype]
@@ -448,12 +450,15 @@ def test_flash_grads_match_xla_autodiff(rng, hq, hkv, causal, window):
                                    err_msg=f"d{name}")
 
 
-@pytest.mark.parametrize("sq,sk,window", [(128, 256, None), (64, 256, 96),
-                                          (128, 512, None)])
-def test_flash_grads_offset_shapes(rng, sq, sk, window):
-    """sq != sk (prefill-with-cache q_offset = sk - sq) backward parity."""
+@pytest.mark.parametrize("sq,sk,window,block", [
+    (128, 256, None, 64), (64, 256, 96, 64), (128, 512, None, 64),
+    (512, 1536, 384, None)])
+def test_flash_grads_offset_shapes(rng, sq, sk, window, block):
+    """sq != sk (prefill-with-cache q_offset = sk - sq) backward parity,
+    at 64-row blocks and at the picked tiles (fp32, window 384: 128 × 128,
+    the window band trimmed over several kv tiles)."""
     q, k, v, co = _flash_case(rng, sq, sk, 4, 2, 64)
-    kw = dict(block_q=64, block_k=64)
+    kw = dict(block_q=block, block_k=block)
     g_int = jax.grad(_flash_loss("interpret", co, True, window, **kw),
                      argnums=(0, 1, 2))(q, k, v)
     g_xla = jax.grad(_flash_loss("xla", co, True, window),
@@ -463,13 +468,17 @@ def test_flash_grads_offset_shapes(rng, sq, sk, window):
                                    err_msg=f"d{name}")
 
 
-@pytest.mark.parametrize("sq,sk", [(100, 100), (129, 257), (251, 251)])
-def test_flash_grads_awkward_lengths(rng, sq, sk):
+@pytest.mark.parametrize("sq,sk,block", [
+    (100, 100, 64), (129, 257, 64), (251, 251, 64), (100, 100, None),
+    (129, 257, None), (700, 700, None)])
+def test_flash_grads_awkward_lengths(rng, sq, sk, block):
     """Odd (non-block-multiple) lengths run the Pallas path via the
     mask-safe pad+slice in ops.flash_attention_op — forward AND backward
-    (padded-key grads masked to zero, padded-query cotangents sliced)."""
+    (padded-key grads masked to zero, padded-query cotangents sliced).
+    With picked tiles a length pads to the next multiple of 128 (700 to
+    768, two fp32 tiles of 384) or is one tile (100)."""
     q, k, v, co = _flash_case(rng, sq, sk, 4, 2, 32)
-    kw = dict(block_q=64, block_k=64)
+    kw = dict(block_q=block, block_k=block)
     o_int = ops.flash_attention_op(q, k, v, backend="interpret", **kw)
     o_xla = ops.flash_attention_op(q, k, v, backend="xla")
     assert o_int.shape[-2] == sq
@@ -483,30 +492,41 @@ def test_flash_grads_awkward_lengths(rng, sq, sk):
                                    err_msg=f"d{name}")
 
 
-def test_flash_grads_traced_q_offset(rng):
+@pytest.mark.parametrize("sq,sk,dh,dtype,block", [
+    (64, 256, 32, jnp.float32, 64), (1024, 2048, 64, jnp.bfloat16, None),
+    (512, 1024, 64, jnp.float32, None)])
+def test_flash_grads_traced_q_offset(rng, sq, sk, dh, dtype, block):
     """The LASP-2H sharded path passes the rank offset t·C as a traced
     scalar: the kernel masks at runtime (band untrimmed) and the
-    custom_vjp returns a float0 cotangent for it."""
-    q, k, v, co = _flash_case(rng, 64, 256, 4, 2, 32)
-    for off in (0, 64, 192):
+    custom_vjp returns a float0 cotangent for it. At picked tiles (bf16:
+    1024 × 1024, fp32: 512 × 512) the offset sk - sq puts a full tile and
+    a diagonal-crossing one in a call, and an offset off the tile grid
+    masks both; bf16 is held to the fp32 reference of its own values."""
+    q, k, v, co = _flash_case(rng, sq, sk, 4, 2, dh, dtype=dtype)
+    up = [x.astype(jnp.float32) for x in (q, k, v)]
+    tol = GRAD_TOL if dtype == jnp.float32 else 4e-2
+    for off in (0, (sk - sq) // 3, sk - sq):
         gi = jax.jit(jax.grad(
             lambda a, b, c, o_: jnp.sum(ops.flash_attention_op(
-                a, b, c, causal=True, backend="interpret", block_q=64,
-                block_k=64, q_offset=o_) * co), argnums=(0, 1, 2)))(
-                    q, k, v, jnp.int32(off))
+                a, b, c, causal=True, backend="interpret", block_q=block,
+                block_k=block, q_offset=o_).astype(jnp.float32) * co),
+            argnums=(0, 1, 2)))(q, k, v, jnp.int32(off))
         gx = jax.grad(
             lambda a, b, c: jnp.sum(ops.flash_attention_op(
                 a, b, c, causal=True, backend="xla", q_offset=off) * co),
-            argnums=(0, 1, 2))(q, k, v)
+            argnums=(0, 1, 2))(*up)
         for name, a_, b_ in zip("q k v".split(), gi, gx):
-            np.testing.assert_allclose(a_, b_, rtol=GRAD_TOL, atol=GRAD_TOL,
+            assert a_.dtype == dtype
+            np.testing.assert_allclose(np.asarray(a_, np.float32), b_,
+                                       rtol=tol, atol=tol,
                                        err_msg=f"d{name} @offset {off}")
 
 
-def test_flash_grads_bf16_inputs(rng):
+@pytest.mark.parametrize("block", [64, None])
+def test_flash_grads_bf16_inputs(rng, block):
     """bf16 q/k/v: cotangents flow back in bf16 with fp32 kernel math."""
     q, k, v, co = _flash_case(rng, 128, 128, 4, 2, 64, dtype=jnp.bfloat16)
-    kw = dict(block_q=64, block_k=64)
+    kw = dict(block_q=block, block_k=block)
     g_int = jax.grad(_flash_loss("interpret", co, True, None, **kw),
                      argnums=(0, 1, 2))(q, k, v)
     g_xla = jax.grad(_flash_loss("xla", co, True, None),
@@ -552,3 +572,77 @@ def test_flash_causal_band_static_trim():
     lo, hi, w = _q_band(nq=8, nkv=8, block_q=64, block_k=64, q_offset=0,
                         causal=True, sliding_window=64)
     assert w == 2
+
+
+@pytest.mark.parametrize("sq,sk,window,dtype,tiles", [
+    # one rank of hybrid-train-sp4-64k: q 16,384 against K/V 65,536
+    (16384, 65536, None, jnp.bfloat16, (1024, 1024)),
+    (16384, 65536, None, jnp.float32, (512, 512)),
+    # a short prefill against its cache: 1408 = 11 × 128
+    (384, 1408, None, jnp.bfloat16, (384, 128)),
+    (1536, 4096, None, jnp.bfloat16, (768, 1024)),
+    # a 100-token prompt is one whole tile; padded lengths
+    (100, 100, None, jnp.bfloat16, (100, 100)),
+    (768, 768, None, jnp.float32, (384, 384)),
+    # the 1/4 hybrid's window 2048 and smaller windows: a quarter of it
+    (4096, 4096, 2048, jnp.bfloat16, (512, 512)),
+    (4096, 4096, 1024, jnp.float32, (256, 256)),
+    (4096, 4096, 64, jnp.bfloat16, (128, 128)),
+])
+def test_flash_pick_tiles(sq, sk, window, dtype, tiles):
+    from repro.kernels.flash_attention import pick_tiles
+    assert pick_tiles(sq, sk, sliding_window=window, dtype=dtype) == tiles
+
+
+def test_flash_pick_tiles_divide_padded_lengths():
+    """Every picked tile divides its (128-padded) length and is a 128
+    multiple, or is the whole length when that is at most 128; the
+    dispatch pads an odd length by less than 128."""
+    from repro.kernels.flash_attention import (MAX_TILE_K, MAX_TILE_Q,
+                                               pick_tiles)
+    for n in list(range(1, 300)) + list(range(300, 70000, 997)):
+        padded = n if n <= 128 else -(-n // 128) * 128
+        assert padded - n < 128
+        bq, bk = pick_tiles(padded, padded)
+        for t, cap in ((bq, MAX_TILE_Q), (bk, MAX_TILE_K)):
+            assert padded % t == 0
+            assert t == padded <= 128 or (t % 128 == 0 and t <= cap)
+
+
+def test_flash_picked_tiles_reach_the_kernel(monkeypatch):
+    """flash_attention_op hands the kernel the picked tiles (bf16, window
+    2048: 512 × 512 at 4096) and honours explicit ones."""
+    from repro.kernels import flash_attention as fa
+    seen = []
+    real = fa._flash
+
+    def spy(*a):
+        seen.append(a[9:11])
+        return real(*a)
+
+    monkeypatch.setattr(fa, "_flash", spy)
+    q = jnp.zeros((1, 1, 4096, 128), jnp.bfloat16)
+    jax.eval_shape(lambda x: ops.flash_attention_op(
+        x, x, x, sliding_window=2048, backend="interpret"), q)
+    jax.eval_shape(lambda x: ops.flash_attention_op(
+        x, x, x, block_q=64, block_k=256, backend="interpret"), q)
+    assert seen == [(512, 512), (64, 256)]
+
+
+def test_flash_two_term_products():
+    """An fp32 tile against a bf16 one goes to the MXU as two bf16 terms:
+    the product keeps 16 significant bits of the fp32 side, far closer to
+    the fp32 product than one bf16 rounding of it."""
+    from repro.kernels.flash_attention import _NN, _dot
+    ks = jax.random.split(jax.random.PRNGKey(0), 2)
+    p = jax.random.uniform(ks[0], (128, 256), jnp.float32)
+    v = jax.random.normal(ks[1], (256, 128), jnp.float32).astype(
+        jnp.bfloat16)
+    exact = np.asarray(p, np.float64) @ np.asarray(v, np.float64)
+    two = np.asarray(_dot(p, v, _NN), np.float64)
+    one = np.asarray(_dot(p.astype(jnp.bfloat16), v, _NN), np.float64)
+    scale = np.max(np.abs(exact))
+    err_two = np.max(np.abs(two - exact)) / scale
+    err_one = np.max(np.abs(one - exact)) / scale
+    assert err_two < 2e-5, err_two
+    assert err_one > 30 * err_two, (err_one, err_two)
